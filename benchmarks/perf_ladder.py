@@ -66,4 +66,6 @@ def main(out_dir="out/dryrun"):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

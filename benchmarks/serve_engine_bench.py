@@ -362,7 +362,7 @@ def bench_mesh(api, anchor, params, *, mesh_spec, slots, max_len,
     On CPU run under XLA_FLAGS=--xla_force_host_platform_device_count=N
     to expose enough host devices.
     """
-    from repro.launch.mesh import parse_mesh
+    from repro.launch.mesh import make_debug_mesh, parse_mesh
     n_data, n_model = parse_mesh(mesh_spec)
     need = n_data * n_model
     if len(jax.devices()) < need:
@@ -370,7 +370,7 @@ def bench_mesh(api, anchor, params, *, mesh_spec, slots, max_len,
             f"--mesh {mesh_spec} needs {need} devices; only "
             f"{len(jax.devices())} visible — on CPU set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
-    mesh = jax.make_mesh((n_data, n_model), ("data", "model"))
+    mesh = make_debug_mesh(n_data, n_model)
     rng = np.random.default_rng(0)
     is_long = lambda i: i % long_every == 1 % long_every
     prompts = [rng.integers(0, vocab,
@@ -445,7 +445,7 @@ def bench_mesh(api, anchor, params, *, mesh_spec, slots, max_len,
 
 
 def bench_slo(api, anchor, params, *, slots, max_len, horizon, wl_seed,
-              page_size=8, burst_thresh=6):
+              page_size=8, burst_thresh=6, hbm_bytes_per_s=None):
     """The --slo sweep (docs/serving_internals.md §10): SLO-tiered serving
     from the measured cost model vs the static queue-depth policy, on the
     SAME deterministic bursty multi-tenant workload.
@@ -572,7 +572,8 @@ def bench_slo(api, anchor, params, *, slots, max_len, horizon, wl_seed,
     _, reqs_b = make_workload(ttft_ms=ttft_budget, tpot_ms=tpot_budget)
     cost = CostModel.from_roofline(
         cfg, [f for _, f in ladder], max_len=max_len, kv_layout="paged",
-        kv_page_size=page_size, block_size=32)
+        kv_page_size=page_size, block_size=32,
+        hbm_bytes_per_s=hbm_bytes_per_s)
     pol_b = FormatPolicy(anchor="mxint8", ladder=ladder, cost=cost)
     eng_b, st_b, dt_b = run(reqs_b, pol_b, "slo")
 
@@ -723,6 +724,11 @@ def main():
                     help="draft rung for --speculative")
     ap.add_argument("--k", type=int, default=4,
                     help="draft depth for --speculative")
+    ap.add_argument("--hbm-bytes-per-s", type=float, default=None,
+                    help="HBM bandwidth the --slo cost model's prior "
+                         "divides by (default: the device's published "
+                         "peak, launch/mesh.py::PEAKS; a CPU host has "
+                         "none, so pass a nominal one there)")
     ap.add_argument("--slo", action="store_true",
                     help="run the SLO-tier sweep instead of the perf "
                          "matrix: static queue-depth policy vs measured-"
@@ -768,7 +774,8 @@ def main():
     if args.slo:
         bench_slo(api, anchor, params, slots=args.slots,
                   max_len=args.max_len, horizon=args.horizon,
-                  wl_seed=args.wl_seed, page_size=args.page_size)
+                  wl_seed=args.wl_seed, page_size=args.page_size,
+                  hbm_bytes_per_s=args.hbm_bytes_per_s)
         return
 
     if args.speculative:
@@ -933,4 +940,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -85,6 +85,10 @@ def main():
                     help="demo SLO-tiered serving: tiered admission + "
                          "cost-model format picks on a bursty two-tenant "
                          "trace (docs/serving_internals.md §10)")
+    ap.add_argument("--hbm-bytes-per-s", type=float, default=None,
+                    help="HBM bandwidth the --slo cost model's prior "
+                         "divides by (default: the device's published "
+                         "peak; a CPU host has none, so pass one there)")
     args = ap.parse_args()
 
     cfg = get_reduced(args.arch)
@@ -182,7 +186,8 @@ def main():
                            hysteresis=1,
                            cost=CostModel.from_roofline(
                                cfg, ("mxint4", "mxint8"), max_len=64,
-                               kv_layout="paged", kv_page_size=8))
+                               kv_layout="paged", kv_page_size=8,
+                               hbm_bytes_per_s=args.hbm_bytes_per_s))
         slo_eng = ElasticEngine(api, anchor, batch_slots=2, max_len=64,
                                 policy=pol, param_template=params,
                                 kv_layout="paged", kv_page_size=8,
@@ -286,4 +291,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

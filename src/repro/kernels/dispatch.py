@@ -22,9 +22,11 @@ static table refined by autotuned entries registered per ``(shape, fmt)``
 from ``benchmarks/kernels_bench.py``.
 
 Fallback conditions (leaf not 2D after scan slicing, legacy split-K int4
-layout, non-even shapes) silently take the densify path; ``stats()`` counts
-which path each traced call took so benchmarks and CI can assert the fused
-kernels are actually live.
+layout, K not a block-size multiple) take the densify path under mode
+"auto"; under an explicit mode "pallas" a 2D leaf the kernels cannot take
+raises instead, so a fused engine never serves a densified GEMM unseen.
+``stats()`` counts which path each traced call took so benchmarks and CI
+can assert the fused kernels are actually live.
 
 Layout conventions this layer depends on — scan-stale leaf metadata
 (contraction dim re-derived as ndim-2), moved-last ``(N, K/bs)`` scales
@@ -33,6 +35,7 @@ are documented in docs/serving_internals.md §§1-3.
 """
 from __future__ import annotations
 
+import math
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -95,7 +98,10 @@ _TILE_CACHE: Dict[Tuple[int, int, int, int, str, str],
                   Tuple[int, int, int]] = {}
 
 # Hard ceilings keeping one (TM,TK)+(TK,TN) operand pair comfortably in VMEM.
+# A dim no larger than its _WHOLE cap may instead be taken as one whole tile.
 _TM_CAP, _TN_CAP, _TK_CAP = 128, 256, 512
+_TN_WHOLE_CAP, _TK_WHOLE_CAP = 1024, 1024
+_LANE, _SUBLANE = 128, 8
 
 
 def register_tiles(m: int, k: int, n: int, fmt_name: str,
@@ -128,32 +134,63 @@ def _best_tile(dim: int, unit: int, cap: int) -> int:
     return best
 
 
+def _k_unit(bs: int) -> int:
+    """Smallest split-K tile Mosaic takes: a lane multiple (the (tm, tk)
+    activation block) whose scale block (tk/bs, tn) is a sublane multiple."""
+    return math.lcm(_LANE, _SUBLANE * bs)
+
+
+def tiles_aligned(m: int, k: int, n: int, tiles: Tuple[int, int, int],
+                  bs: int, kind: str = "mx") -> bool:
+    """Whether ``(tm, tn, tk)`` meets the Mosaic block rules for this call.
+
+    tm is a sublane multiple. tk is a multiple of ``_k_unit(bs)`` or the
+    whole K. tn is a lane multiple, or (unpacked kind only) the whole N —
+    the int4 kernel's scale block spans both packed halves, so a whole
+    half-N tile is never a whole scale dim there.
+    """
+    tm, tn, tk = tiles
+    n_eff = n // 2 if kind == "int4" else n
+    tn_ok = tn > 0 and (tn % _LANE == 0 or (kind != "int4" and tn == n_eff))
+    tk_ok = tk > 0 and (tk % _k_unit(bs) == 0 or tk == _round_up(k, bs))
+    return tm > 0 and tm % _SUBLANE == 0 and tn_ok and tk_ok
+
+
 def select_tiles(m: int, k: int, n: int, fmt: MXFormat,
                  kind: str = "mx") -> Tuple[int, int, int]:
     """(tm, tn, tk) for an (M, K, N) qmatmul at ``fmt``.
 
     Autotuned entries win; otherwise tiles are picked to minimize zero
-    padding subject to VMEM-friendly caps — sublane multiples of 8 for M,
-    lane-dim multiples of 8 (128 when it divides) for N, block-size
-    multiples for K so scales tile alongside the weight.
+    padding subject to VMEM-friendly caps and the Mosaic block rules of
+    ``tiles_aligned``: sublane multiples for M; lane multiples for N, or
+    the whole N when it is small and not lane-aligned; ``_k_unit(bs)``
+    multiples for K, or the whole K when it is small — so both the
+    activation block and the scale block tile.
 
     ``(m, k, n)`` are whatever shapes this trace actually sees — per-shard
     local dims inside shard_map — and the lookup keys on them plus
     ``fmt.block_size``, so a cached entry can never pick tiles that don't
     divide the shapes (or scale blocking) of the call at hand. A registered
-    entry that nonetheless violates the kernel's alignment rules (stale
+    entry that nonetheless violates the alignment rules (stale
     hand-registration) is ignored, not applied.
     """
     bs = fmt.block_size
     key = (m, k, n, bs, fmt.name, kind)
-    if key in _TILE_CACHE:
-        tm, tn, tk = _TILE_CACHE[key]
-        if tm % 8 == 0 and tn > 0 and tk % bs == 0:
-            return tm, tn, tk
+    if key in _TILE_CACHE and tiles_aligned(m, k, n, _TILE_CACHE[key], bs,
+                                            kind):
+        return _TILE_CACHE[key]
     n_eff = n // 2 if kind == "int4" else n
-    tm = _best_tile(m, 8, _TM_CAP)
-    tn = 128 if n_eff % 128 == 0 else _best_tile(n_eff, 8, _TN_CAP)
-    tk = _best_tile(k, bs, max(bs, (_TK_CAP // bs) * bs))
+    tm = _best_tile(m, _SUBLANE, _TM_CAP)
+    if n_eff % _LANE and kind != "int4" and n_eff <= _TN_WHOLE_CAP:
+        tn = n_eff
+    else:
+        tn = _LANE if n_eff % _LANE == 0 else _best_tile(n_eff, _LANE,
+                                                         _TN_CAP)
+    unit = _k_unit(bs)
+    kw = _round_up(k, bs)
+    tk = _best_tile(kw, unit, max(unit, _TK_CAP))
+    if kw <= _TK_WHOLE_CAP and _round_up(kw, tk) > kw:
+        tk = kw                   # one whole-K tile beats padding K
     return tm, tn, tk
 
 
@@ -255,6 +292,12 @@ def _fused_supported(leaf) -> bool:
     return False
 
 
+def _codes_2d(leaf) -> Optional[jax.Array]:
+    """The leaf's 2D code array (codes or nibble-packed), else None."""
+    arr = getattr(leaf, "codes", getattr(leaf, "packed", None))
+    return arr if arr is not None and arr.ndim == 2 else None
+
+
 def qmatmul(x: jax.Array, leaf, *, mode: Optional[str] = None,
             block_size: int = 32, tiles: Optional[Tuple] = None,
             out_dtype=None) -> jax.Array:
@@ -269,7 +312,14 @@ def qmatmul(x: jax.Array, leaf, *, mode: Optional[str] = None,
     """
     out_dtype = out_dtype or x.dtype
     _check_serving_layout(leaf)
-    use_pallas = resolve_mode(mode) == "pallas" and _fused_supported(leaf)
+    supported = _fused_supported(leaf)
+    if mode == "pallas" and not supported and _codes_2d(leaf) is not None:
+        raise ValueError(
+            f"qmatmul(mode='pallas'): {type(leaf).__name__} leaf "
+            f"{tuple(_codes_2d(leaf).shape)} "
+            f"(layout {getattr(leaf, 'layout', 'unpacked')}) cannot take "
+            "the fused kernel; serve it with mode='densify' (fused=False)")
+    use_pallas = resolve_mode(mode) == "pallas" and supported
     if not use_pallas:
         from repro.serve.packed_params import densify_leaf
         _stats["densify"] += 1

@@ -135,12 +135,64 @@ def pages_read_mq(q_offset: int, q_len: int, page_size: int,
 
 
 # ---------------------------------------------------------------------------
-# The kernel
+# The kernels
 # ---------------------------------------------------------------------------
+# Mosaic-facing layout: one layer's pool is (P, ps, Hkv*D), so a page block
+# (ps, Hkv*D) tiles on its last two dims and kv head h is the static lane
+# slice [h*D, (h+1)*D). Queries arrive head-grouped as (Hkv, rows, D) and
+# every product is a plain 2-D dot per kv head — no in-kernel reshape of a
+# tiled value, which Mosaic refuses. Running (max, sum-exp) are (rows, 1)
+# columns per kv head.
+def _init_scratch(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _fold_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask, vvalid, *,
+               hkv: int, d: int):
+    """Fold one page into every kv head's flash partial softmax.
+
+    ``mask`` (rows, ps) says which (query row, key) pairs are live,
+    ``vvalid`` (ps, 1) which V rows may enter the PV product. Masking is
+    total: scores are -inf'd BEFORE the max (dead positions may hold NaN —
+    poisoned / recycled pages — and NaN propagates through jnp.maximum), p
+    is re-zeroed after the exp, and V rows are zeroed too (0 * NaN = NaN in
+    the PV product) — this triple is what the NaN-poison tests pin down.
+    ``alpha`` keeps the ``m == -inf`` guard: a row that has not seen a
+    valid position yet would otherwise NaN on exp(-inf - -inf).
+    """
+    scale = 1.0 / (d ** 0.5)
+    for h in range(hkv):
+        cols = slice(h * d, (h + 1) * d)
+        q = q_ref[0, h].astype(jnp.float32)                  # (rows, D)
+        k = k_ref[0, :, cols].astype(jnp.float32)            # (ps, D)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask, s, -jnp.inf)
+        m_prev = m_ref[h]                                    # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(jnp.where(m_new > -jnp.inf, m_prev - m_new, 0.0))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        v = jnp.where(vvalid, v_ref[0, :, cols].astype(jnp.float32), 0.0)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
+
+
+def _finalize(o_ref, l_ref, acc_ref, *, hkv: int):
+    for h in range(hkv):
+        l = l_ref[h]
+        out = acc_ref[h] / jnp.maximum(l, 1e-30)
+        # rows that saw no valid position (cache_len == 0, dead lanes) -> 0
+        o_ref[0, h] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
+
+
 def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
                        m_ref, l_ref, acc_ref, *,
                        page_size: int, window: Optional[int],
-                       hkv: int, g: int):
+                       hkv: int, d: int):
     """One (slot, logical-block) grid step of the flash partial softmax.
 
     ``bt_ref``/``cl_ref`` are the scalar-prefetched block table and
@@ -150,55 +202,42 @@ def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
-    mp = pl.num_programs(1)
-    d = q_ref.shape[-1]
-    scale = 1.0 / (d ** 0.5)
+    g = q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
     cache_len = cl_ref[b]
-    pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)[0]
-    valid = pos < cache_len
-    if window is not None:
-        valid &= pos >= cache_len - window
 
-    # Skip pages with no live position: keeps the running max finite (a
-    # wholly-masked page would be all -inf and poison the carry with
-    # exp(-inf - -inf) = NaN) and skips the FLOPs past the frontier.
-    @pl.when(jnp.any(valid))
+    def live(pos):
+        ok = pos < cache_len
+        if window is not None:
+            ok &= pos >= cache_len - window
+        return ok
+
+    mask = live(j * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (g, page_size), 1))
+    vvalid = live(j * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (page_size, 1), 0))
+
+    # Skip pages with no live position: keeps the running max finite and
+    # skips the FLOPs past the frontier.
+    @pl.when(jnp.any(vvalid))
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32).reshape(hkv, g, d)
-        k = k_ref[0].astype(jnp.float32)             # (ps, Hkv, D)
-        s = jnp.einsum("kgd,tkd->kgt", q, k) * scale
-        # Mask BEFORE the max — dead positions may hold NaN (poisoned /
-        # recycled pages) and NaN propagates through jnp.maximum.
-        s = jnp.where(valid[None, None, :], s, -jnp.inf)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        # p is already 0 at dead positions (exp(-inf)) but 0 * NaN = NaN in
-        # the PV product, so the V rows are zeroed too — this pair is what
-        # the NaN-poison tests pin down.
-        p = jnp.where(valid[None, None, :], p, 0.0)
-        v = jnp.where(valid[:, None, None],
-                      v_ref[0].astype(jnp.float32), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + \
-            jnp.einsum("kgt,tkd->kgd", p, v)
-        m_ref[...] = m_new
+        _fold_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask, vvalid,
+                   hkv=hkv, d=d)
 
-    @pl.when(j == mp - 1)
-    def _finalize():
-        l = l_ref[...]
-        out = acc_ref[...] / jnp.maximum(l, 1e-30)[..., None]
-        out = jnp.where(l[..., None] > 0, out, 0.0)   # cache_len==0 -> zeros
-        o_ref[0] = out.reshape(hkv * g, d).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _done():
+        _finalize(o_ref, l_ref, acc_ref, hkv=hkv)
+
+
+def _pool_heads(k_pages: jax.Array, d: int):
+    """(page_size, Hkv) of a (P, ps, Hkv*D) pool for head dim ``d``."""
+    ps, width = k_pages.shape[1], k_pages.shape[2]
+    assert width % d == 0, (k_pages.shape, d)
+    return ps, width // d
 
 
 def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
@@ -208,15 +247,15 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                            interpret: bool = False) -> jax.Array:
     """Single-token attention straight off the page pool: (B, H, D) f32.
 
-    q (B, H, D); k_pages/v_pages (P, page_size, Hkv, D) — ONE layer's pool;
-    block_table (B, max_pages) int32 physical page ids (0 = unmapped /
-    scratch); cache_len (B,) int32 live lengths (may be traced). The block
-    table and cache_len ride as scalar-prefetch operands so the KV index
-    maps can translate logical block → physical page before each DMA.
+    q (B, H, D); k_pages/v_pages (P, page_size, Hkv*D) — ONE layer's pool,
+    kv heads flattened head-major into the last axis; block_table
+    (B, max_pages) int32 physical page ids (0 = unmapped / scratch);
+    cache_len (B,) int32 live lengths (may be traced). The block table and
+    cache_len ride as scalar-prefetch operands so the KV index maps can
+    translate logical block → physical page before each DMA.
     """
     b, h, d = q.shape
-    ps = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    ps, hkv = _pool_heads(k_pages, d)
     mp = block_table.shape[1]
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
@@ -234,30 +273,30 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         if window is not None:
             first = jnp.clip((cl[bi] - window) // ps, 0, last)
             jc = jnp.maximum(jc, first)
-        return (bt[bi, jc], 0, 0, 0)
+        return (bt[bi, jc], 0, 0)
 
+    q_spec = pl.BlockSpec((1, hkv, g, d), lambda bi, j, bt, cl: (bi, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, mp),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda bi, j, bt, cl: (bi, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, d), kv_index),
-            pl.BlockSpec((1, ps, hkv, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda bi, j, bt, cl: (bi, 0, 0)),
+        in_specs=[q_spec,
+                  pl.BlockSpec((1, ps, hkv * d), kv_index),
+                  pl.BlockSpec((1, ps, hkv * d), kv_index)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((hkv, g), jnp.float32),       # running max
-            pltpu.VMEM((hkv, g), jnp.float32),       # running sum-exp
+            pltpu.VMEM((hkv, g, 1), jnp.float32),    # running max
+            pltpu.VMEM((hkv, g, 1), jnp.float32),    # running sum-exp
             pltpu.VMEM((hkv, g, d), jnp.float32),    # running PV acc
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, page_size=ps, window=window,
-                          hkv=hkv, g=g),
+                          hkv=hkv, d=d),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
         interpret=interpret,
-    )(block_table, cache_len, q, k_pages, v_pages)
+    )(block_table, cache_len, q.reshape(b, hkv, g, d), k_pages, v_pages)
+    return out.reshape(b, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -266,74 +305,52 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
 def _paged_attn_mq_kernel(bt_ref, qo_ref, ql_ref, q_ref, k_ref, v_ref, o_ref,
                           m_ref, l_ref, acc_ref, *,
                           page_size: int, window: Optional[int],
-                          hkv: int, g: int, tq: int):
+                          hkv: int, d: int, g: int, tq: int):
     """One (slot, q-block, logical-KV-block) grid step.
 
-    The q_len==1 kernel above with a query axis: each slot carries ``tq``
-    query lanes per q block; lane ``i`` of block ``qi`` sits at logical
-    position ``q_offset + qi*tq + i`` and is live iff ``qi*tq + i < q_len``.
-    Scratch persists across the j-minor KV walk of one (slot, q block);
-    masking stays total (scores -inf'd before the max, p re-zeroed after the
-    exp, V rows zeroed) so poisoned pages and the garbage under dead query
-    lanes never reach the reduction. Unlike the single-query kernel, a page
-    the walk visits can be live for some lanes and dead for others, so the
-    running max is per-lane and the carry ``alpha`` needs the
-    ``m == -inf`` guard (exp(-inf - -inf) would NaN a lane that has not
-    seen a valid position yet).
+    The q_len==1 kernel above with a query axis: each kv head's query rows
+    are ``tq`` lanes x ``g`` grouped heads, row ``r`` = lane ``r // g``;
+    lane ``i`` of block ``qi`` sits at logical position
+    ``q_offset + qi*tq + i`` and is live iff ``qi*tq + i < q_len``.
+    Scratch persists across the j-minor KV walk of one (slot, q block).
+    Unlike the single-query kernel, a page the walk visits can be live for
+    some lanes and dead for others, so the running max is per row.
     """
     b = pl.program_id(0)
     qi = pl.program_id(1)
     j = pl.program_id(2)
-    mp = pl.num_programs(2)
-    d = q_ref.shape[-1]
-    scale = 1.0 / (d ** 0.5)
+    rows = tq * g
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
     q_offset = qo_ref[b]
     q_len = ql_ref[b]
     live = q_offset + q_len                 # KV frontier after this tick
+    base = q_offset + qi * tq               # position of the block's lane 0
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
     pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)[0]
-    qidx = qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)[:, 0]
-    qpos = q_offset + qidx
-    # (tq, ps): causal self-inclusive, clipped at the frontier, windowed,
-    # and dead for pad lanes past q_len.
-    mask = (pos[None, :] <= qpos[:, None]) & (pos[None, :] < live)
-    mask &= (qidx < q_len)[:, None]
+        jnp.int32, (rows, page_size), 1)
+    # (rows, ps), with lane i = r // g compared through r alone (no vector
+    # integer division): causal self-inclusive (pos <= base + i), clipped
+    # at the frontier, dead for pad lanes (i < q_len - qi*tq), windowed
+    # (base + i - pos < W).
+    mask = (r >= (pos - base) * g) & (pos < live)
+    mask &= r < (q_len - qi * tq) * g
     if window is not None:
-        mask &= qpos[:, None] - pos[None, :] < window
-    vvalid = pos < live
+        mask &= r < (pos + window - base) * g
+    vvalid = j * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (page_size, 1), 0) < live
 
     @pl.when(jnp.any(mask))
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32).reshape(tq, hkv, g, d)
-        k = k_ref[0].astype(jnp.float32)             # (ps, Hkv, D)
-        s = jnp.einsum("qkgd,tkd->kgqt", q, k) * scale
-        s = jnp.where(mask[None, None], s, -jnp.inf)
-        m_prev = m_ref[...]                          # (Hkv, G, tq)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(jnp.where(m_new > -jnp.inf, m_prev - m_new, 0.0))
-        p = jnp.exp(s - m_new[..., None])
-        p = jnp.where(mask[None, None], p, 0.0)
-        v = jnp.where(vvalid[:, None, None],
-                      v_ref[0].astype(jnp.float32), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[..., None] + \
-            jnp.einsum("kgqt,tkd->kgqd", p, v)
-        m_ref[...] = m_new
+        _fold_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask, vvalid,
+                   hkv=hkv, d=d)
 
-    @pl.when(j == mp - 1)
-    def _finalize():
-        l = l_ref[...]
-        out = acc_ref[...] / jnp.maximum(l, 1e-30)[..., None]
-        out = jnp.where(l[..., None] > 0, out, 0.0)   # dead lanes -> zeros
-        o_ref[0] = out.transpose(2, 0, 1, 3).reshape(
-            tq, hkv * g, d).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _done():
+        _finalize(o_ref, l_ref, acc_ref, hkv=hkv)
 
 
 def paged_attention_pallas_mq(q: jax.Array, k_pages: jax.Array,
@@ -346,16 +363,17 @@ def paged_attention_pallas_mq(q: jax.Array, k_pages: jax.Array,
 
     q (B, C, H, D) — row b's query ``i`` sits at logical position
     ``q_offset[b] + i`` and is live iff ``i < q_len[b]`` (decode rows carry
-    C-1 dead pad lanes; the mid-prefill row is mostly live). The pool must
-    already hold each row's new K/V at those positions. ``tq`` is the q
-    block size (defaults to C — one block; must divide C); the KV walk per
-    (row, q block) is clamped to the pages that block's live queries can
-    see, so DMA cost follows ``pages_read_mq``, and dead q blocks collapse
-    to one elided page. Dead lanes output exact zeros.
+    C-1 dead pad lanes; the mid-prefill row is mostly live). The pools
+    (P, page_size, Hkv*D) must already hold each row's new K/V at those
+    positions. ``tq`` is the q block size (defaults to C — one block; must
+    divide C, and on TPU ``tq * H/Hkv`` must be a multiple of 8 unless it
+    is C); the KV walk per (row, q block) is clamped to the pages that
+    block's live queries can see, so DMA cost follows ``pages_read_mq``,
+    and dead q blocks collapse to one elided page. Dead lanes output exact
+    zeros.
     """
     b, c, h, d = q.shape
-    ps = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    ps, hkv = _pool_heads(k_pages, d)
     mp = block_table.shape[1]
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
@@ -373,32 +391,35 @@ def paged_attention_pallas_mq(q: jax.Array, k_pages: jax.Array,
         if window is not None:
             first = jnp.clip((qo[bi] + qi * tq + 1 - window) // ps, 0, last)
             jc = jnp.maximum(jc, first)
-        return (bt[bi, jc], 0, 0, 0)
+        return (bt[bi, jc], 0, 0)
 
+    # head-grouped rows: (B, Hkv, C*G, D), row = lane * G + grouped head
+    qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, c * g, d)
+    q_spec = pl.BlockSpec((1, hkv, tq * g, d),
+                          lambda bi, qi, j, bt, qo, ql: (bi, 0, qi, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, nq, mp),
-        in_specs=[
-            pl.BlockSpec((1, tq, h, d),
-                         lambda bi, qi, j, bt, qo, ql: (bi, qi, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, d), kv_index),
-            pl.BlockSpec((1, ps, hkv, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, tq, h, d),
-                               lambda bi, qi, j, bt, qo, ql: (bi, qi, 0, 0)),
+        in_specs=[q_spec,
+                  pl.BlockSpec((1, ps, hkv * d), kv_index),
+                  pl.BlockSpec((1, ps, hkv * d), kv_index)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((hkv, g, tq), jnp.float32),     # running max
-            pltpu.VMEM((hkv, g, tq), jnp.float32),     # running sum-exp
-            pltpu.VMEM((hkv, g, tq, d), jnp.float32),  # running PV acc
+            pltpu.VMEM((hkv, tq * g, 1), jnp.float32),   # running max
+            pltpu.VMEM((hkv, tq * g, 1), jnp.float32),   # running sum-exp
+            pltpu.VMEM((hkv, tq * g, d), jnp.float32),   # running PV acc
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_attn_mq_kernel, page_size=ps, window=window,
-                          hkv=hkv, g=g, tq=tq),
+                          hkv=hkv, d=d, g=g, tq=tq),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, d), jnp.float32),
         interpret=interpret,
-    )(block_table, q_offset, q_len, q, k_pages, v_pages)
+    )(block_table, q_offset, q_len, qg, k_pages, v_pages)
+    return out.reshape(b, hkv, c, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, c, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -432,8 +453,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         return out[:, None].astype(q.dtype)
     _stats["fallback"] += 1
     from repro.models.layers import decode_attention, paged_gather
-    return decode_attention(q, paged_gather(k_pages, block_table),
-                            paged_gather(v_pages, block_table),
+    d = q.shape[-1]
+    return decode_attention(q, paged_gather(k_pages, block_table, d),
+                            paged_gather(v_pages, block_table, d),
                             cache_len, window=window)
 
 
@@ -469,6 +491,7 @@ def paged_mixed_attention(q: jax.Array, k_pages: jax.Array,
         return out.astype(q.dtype)
     _stats["fallback_mq"] += 1
     from repro.models.layers import mixed_attention, paged_gather
-    return mixed_attention(q, paged_gather(k_pages, block_table),
-                           paged_gather(v_pages, block_table),
+    d = q.shape[-1]
+    return mixed_attention(q, paged_gather(k_pages, block_table, d),
+                           paged_gather(v_pages, block_table, d),
                            q_offset, q_len, window=window)

@@ -355,7 +355,7 @@ def serve_roofline_terms(cfg: ModelConfig, formats,
     ``n_model``: tensor-parallel shards. The roofline is PER CHIP — a
     meshed engine streams only its weight shard and its kv-head slice of
     every token read, so both terms divide by the mesh's 'model' axis size
-    (the single-chip ``HBM_BW`` the cost model divides by stays a per-chip
+    (the single-chip HBM bandwidth the cost model divides by stays a per-chip
     number either way). Replicated leaves (norms, biases) are O(d_model)
     noise at this granularity, same as the unsharded approximation.
     """
@@ -447,7 +447,10 @@ def collectives_prefill(cfg: ModelConfig, shape: ShapeSpec,
 def roofline(cfg: ModelConfig, shape: ShapeSpec, mesh: MeshDesc,
              weight_bits_decode: int = 16,
              weight_stationary: bool = False) -> Dict[str, float]:
-    from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+    """Roofline terms of one step on ``mesh`` v5e chips (the production
+    fleet; peaks from ``launch.mesh.PEAKS``)."""
+    from repro.launch.mesh import V5E, device_peaks
+    peak = device_peaks(V5E)
     if shape.kind == "train":
         fl = flops_train(cfg, shape)
         hbm = hbm_train(cfg, shape, mesh)
@@ -463,9 +466,9 @@ def roofline(cfg: ModelConfig, shape: ShapeSpec, mesh: MeshDesc,
         coll = collectives_decode(cfg, shape, mesh,
                                   weight_stationary=weight_stationary,
                                   weight_bits=weight_bits_decode)
-    t_comp = fl["total"] / mesh.chips / PEAK_FLOPS_BF16
-    t_mem = hbm / HBM_BW
-    t_coll = coll["total"] / ICI_BW
+    t_comp = fl["total"] / mesh.chips / peak["flops_bf16"]
+    t_mem = hbm / peak["hbm_bytes_per_s"]
+    t_coll = coll["total"] / peak["ici_bytes_per_s_per_link"]
     dominant = max(("compute", t_comp), ("memory", t_mem),
                    ("collective", t_coll), key=lambda kv: kv[1])[0]
     bound = max(t_comp, t_mem, t_coll)

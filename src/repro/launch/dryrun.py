@@ -45,7 +45,6 @@ from repro.configs import (SHAPES, applicable, decode_cache_len,  # noqa: E402
                            get_config, list_archs)
 from repro.core.formats import TRAIN_FORMATS_MXINT  # noqa: E402
 from repro.core.qat import QATConfig                # noqa: E402
-from repro.launch._compat import compiled_cost      # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models import get_model                  # noqa: E402
 from repro.optim.adamw import AdamWConfig, init_opt_state  # noqa: E402
@@ -270,7 +269,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
         compiled = lowered.compile()
 
-    cost = compiled_cost(compiled)
+    cost = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     coll = parse_collective_bytes(hlo)
@@ -336,4 +335,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -27,6 +27,8 @@ def main():
     ap.add_argument("--fmt", default=None,
                     help="pin a format instead of the load policy")
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="published widths instead of the reduced config")
     args = ap.parse_args()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
@@ -60,4 +62,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -212,7 +212,10 @@ def mixed_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 # Paged KV cache (block-table indirection over a shared page pool)
 # =============================================================================
 # Layout (see docs/serving_internals.md): each attention layer owns a page
-# pool (num_pages, page_size, Hkv, D); a slot's KV lives in the physical pages
+# pool (num_pages, page_size, Hkv*D) — kv heads flattened head-major into the
+# last axis, so a page is one (page_size, Hkv*D) tile the paged-attention
+# kernels DMA and slice per head without reshaping; a slot's KV lives in the
+# physical pages
 # its block-table row names, in logical order — position p maps to page
 # row[p // page_size], offset p % page_size. Page 0 is a reserved scratch
 # page: unmapped block-table entries point at it, so retired slots scribble
@@ -245,7 +248,7 @@ def paged_prefill_update(pool: jax.Array, kv_new: jax.Array,
     pad = n_p * ps - s
     if pad:
         kv_new = jnp.pad(kv_new, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    vals = kv_new.astype(pool.dtype).reshape(b * n_p, ps, hkv, d)
+    vals = kv_new.astype(pool.dtype).reshape(b * n_p, ps, hkv * d)
     ids = jax.lax.dynamic_slice_in_dim(block_table, start_pos // ps, n_p,
                                        axis=1).reshape(-1)
     return pool.at[ids].set(vals)
@@ -263,7 +266,8 @@ def paged_decode_append(pool: jax.Array, kv_tok: jax.Array,
     ps = pool.shape[1]
     phys = jnp.take_along_axis(block_table, (cache_len // ps)[:, None],
                                axis=1)[:, 0]
-    return pool.at[phys, cache_len % ps].set(kv_tok[:, 0].astype(pool.dtype))
+    tok = kv_tok[:, 0].reshape(kv_tok.shape[0], -1)
+    return pool.at[phys, cache_len % ps].set(tok.astype(pool.dtype))
 
 
 def mixed_cache_update(cache: jax.Array, kv_new: jax.Array,
@@ -309,11 +313,13 @@ def paged_mixed_update(pool: jax.Array, kv_new: jax.Array,
     blk = jnp.clip(pos // ps, 0, mp - 1)
     phys = jnp.take_along_axis(block_table, blk, axis=1)
     phys = jnp.where(valid & (pos // ps < mp), phys, 0)
-    vals = jnp.where(valid[..., None, None], kv_new.astype(pool.dtype), 0)
+    vals = jnp.where(valid[..., None], kv_new.reshape(*pos.shape, -1)
+                     .astype(pool.dtype), 0)
     return pool.at[phys, pos % ps].set(vals)
 
 
-def paged_gather(pool: jax.Array, block_table: jax.Array) -> jax.Array:
+def paged_gather(pool: jax.Array, block_table: jax.Array,
+                 head_dim: int) -> jax.Array:
     """Materialize each slot's logical KV view: (B, max_pages*ps, Hkv, D).
 
     A plain gather — positions come back in logical order, so the result
@@ -330,8 +336,8 @@ def paged_gather(pool: jax.Array, block_table: jax.Array) -> jax.Array:
     this gather (its flash queries span the whole cache).
     """
     b, mp = block_table.shape
-    pages = pool[block_table]                 # (B, MP, ps, Hkv, D)
-    return pages.reshape(b, mp * pool.shape[1], *pool.shape[2:])
+    pages = pool[block_table]                 # (B, MP, ps, Hkv*D)
+    return pages.reshape(b, mp * pool.shape[1], -1, head_dim)
 
 
 # =============================================================================
@@ -352,7 +358,7 @@ def attention_block(ctx: QuantCtx, x: jax.Array, p, cfg: ModelConfig,
     cache in decode mode.
 
     With ``block_table`` set, ``kv_cache`` holds paged pools
-    (num_pages, page_size, Hkv, D): the new token is appended through the
+    (num_pages, page_size, Hkv*D): the new token is appended through the
     block-table indirection and attention runs over the pool via
     ``paged_decode_attention`` — ``attn_impl="paged_kernel"`` consumes the
     block table directly in the gather-free Pallas kernel
@@ -409,8 +415,8 @@ def attention_block(ctx: QuantCtx, x: jax.Array, p, cfg: ModelConfig,
                                       start_pos=chunk_start)
             vc = paged_prefill_update(vc, v, block_table,
                                       start_pos=chunk_start)
-            k_view = paged_gather(kc, block_table)
-            v_view = paged_gather(vc, block_table)
+            k_view = paged_gather(kc, block_table, hd)
+            v_view = paged_gather(vc, block_table, hd)
         else:
             kc = jax.lax.dynamic_update_slice_in_dim(
                 kc, k.astype(kc.dtype), chunk_start, axis=1)

@@ -616,7 +616,7 @@ def make_model(cfg: ModelConfig, qat: Optional[QATConfig] = None, *,
         (B, s_max, Hkv, D) — s_max HBM is committed per slot up front.
 
         ``kv_layout="paged"``: a shared page pool per layer
-        (num_pages, page_size, Hkv, D) plus a per-slot ``block_table``
+        (num_pages, page_size, Hkv*D) plus a per-slot ``block_table``
         (B, ceil(s_max/page_size)) of physical page ids, so s_max is a
         per-request *bound* and HBM is committed page-by-page as sequences
         grow. Physical page 0 is reserved scratch (unmapped entries point
@@ -648,17 +648,17 @@ def make_model(cfg: ModelConfig, qat: Optional[QATConfig] = None, *,
             num_pages = b * pages_per_slot + 1   # + reserved scratch page 0
         pool = functools.partial(
             jnp.zeros, (cfg.n_groups, num_pages, page_size,
-                        cfg.n_kv_heads, cfg.hd), dtype)
+                        cfg.n_kv_heads * cfg.hd), dtype)
         return {"blocks": [{"k_pages": pool(), "v_pages": pool()}
                            for _ in range(cfg.scan_group)],
                 "block_table": jnp.zeros((b, pages_per_slot), jnp.int32)}
 
     def cache_axes(kv_layout="dense"):
         if kv_layout == "paged":
-            # pools (G, P, ps, Hkv, D): shard the page axis like the dense
+            # pools (G, P, ps, Hkv*D): shard the page axis like the dense
             # sequence axis; the tiny block table replicates per batch row.
-            ax = {"k_pages": (None, "kv_seq", None, None, None),
-                  "v_pages": (None, "kv_seq", None, None, None)}
+            ax = {"k_pages": (None, "kv_seq", None, None),
+                  "v_pages": (None, "kv_seq", None, None)}
             return {"blocks": [dict(ax) for _ in range(cfg.scan_group)],
                     "block_table": ("batch", None)}
         return {"blocks": [_cache_axes_for_block(cfg, j)

@@ -551,23 +551,19 @@ class ElasticEngine:
                 cache_shape["block_table"].shape[1] * kv_page_size
         else:
             self._attn_read_span = self.max_len + api.cfg.vision_tokens
-        # Tensor-parallel cache placement: the 5D leaves (dense K/V
-        # (G, B, S, Hkv, D) and paged pools (G, P, ps, Hkv, D)) shard over
-        # kv heads (axis 3); the block table and every host-built step
-        # argument stay replicated with GLOBAL page ids, so the page
-        # bookkeeping in generate() is mesh-oblivious.
+        # Tensor-parallel cache placement: the K/V leaves (dense
+        # (G, B, S, Hkv, D) and paged pools (G, P, ps, Hkv*D), head-major)
+        # shard over kv heads on axis 3; the block table and every
+        # host-built step argument stay replicated with GLOBAL page ids, so
+        # the page bookkeeping in generate() is mesh-oblivious.
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             self._cache_pspecs = jax.tree_util.tree_map(
-                lambda l: (PartitionSpec(None, None, None, "model", None)
-                           if l.ndim == 5 else PartitionSpec()),
+                lambda l: (PartitionSpec(None, None, None, "model")
+                           if l.ndim >= 4 else PartitionSpec()),
                 cache_shape)
             self._cache_shardings = jax.tree_util.tree_map(
-                lambda l: NamedSharding(
-                    self.mesh,
-                    PartitionSpec(None, None, None, "model", None)
-                    if l.ndim == 5 else PartitionSpec()),
-                cache_shape)
+                lambda p: NamedSharding(self.mesh, p), self._cache_pspecs)
         else:
             self._cache_pspecs = None
             self._cache_shardings = None
@@ -675,7 +671,7 @@ class ElasticEngine:
         if self.mesh is None:
             return jax.jit(fn)
         from jax.sharding import PartitionSpec
-        from repro.train.compression import shard_map
+        from jax import shard_map
         compiled: Dict = {}
 
         def call(weights, *rest):
@@ -795,6 +791,48 @@ class ElasticEngine:
     def set_format(self, fmt_name: str):
         self.current_fmt = fmt_name
         return self.weights_for(fmt_name)
+
+    def prompt_logits(self, prompt, fmt_name: str) -> np.ndarray:
+        """Next-token logits (V,) f32 after prefilling ``prompt`` alone.
+
+        The prompt goes into slot 0 of a fresh cache one ``prefill_chunk``
+        at a time through this engine's own mixed-step executable — its
+        weight contract, attention read path and mesh — with every other
+        row masked exactly as a free slot is during ``generate``. Engines
+        that differ only in those knobs must agree here up to rounding:
+        the logits-level check across contracts and meshes. Needs the
+        mixed scheduler and the paged layout.
+        """
+        if self.scheduler != "mixed" or self.kv_layout != "paged":
+            raise ValueError("prompt_logits needs scheduler='mixed' and "
+                             "kv_layout='paged'")
+        prompt = np.asarray(prompt, np.int32)
+        if not 0 < prompt.size <= self.prompt_capacity:
+            raise ValueError(f"prompt length {prompt.size} outside "
+                             f"[1, {self.prompt_capacity}]")
+        b, c = self.slots, self.prefill_chunk
+        cache = self._init_cache(b)
+        bt = np.zeros(cache["block_table"].shape, np.int32)
+        n_pages = -(-prompt.size // self.kv_page_size)
+        bt[0, :n_pages] = np.arange(1, n_pages + 1)
+        cache["block_table"] = jnp.asarray(bt)
+        if self.mesh is not None:
+            cache = jax.device_put(cache, self._cache_shardings)
+        fn = self._packed_mixed if self._serves_packed(fmt_name) \
+            else self._dense_mixed
+        weights = self.weights_for(fmt_name)
+        cache_len = jnp.zeros((b,), jnp.int32)
+        for start in range(0, prompt.size, c):
+            take = min(c, prompt.size - start)
+            tokens = np.zeros((b, c), np.int32)
+            tokens[0, :take] = prompt[start:start + take]
+            q_len = np.ones(b, np.int32)
+            q_len[0] = take
+            logits, cache = fn(weights, {"tokens": jnp.asarray(tokens),
+                                         "q_len": jnp.asarray(q_len)},
+                               cache, cache_len)
+            cache_len = cache_len.at[0].add(take)
+        return np.asarray(logits[0], np.float32)
 
     # ---- admission helpers ------------------------------------------------
     @property

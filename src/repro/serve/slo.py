@@ -127,9 +127,9 @@ class CostModel:
 
     def __init__(self, hbm_bytes_per_s: Optional[float] = None,
                  ema: float = 0.25, min_ticks: int = 2) -> None:
-        if hbm_bytes_per_s is None:
-            from repro.launch.mesh import HBM_BW
-            hbm_bytes_per_s = HBM_BW
+        if hbm_bytes_per_s is None:      # this device's published peak;
+            from repro.launch.mesh import device_peaks   # unknown kinds raise
+            hbm_bytes_per_s = device_peaks()["hbm_bytes_per_s"]
         if not (0.0 < ema <= 1.0):
             raise ValueError(f"ema must be in (0, 1], got {ema}")
         self.hbm_bytes_per_s = float(hbm_bytes_per_s)
@@ -162,7 +162,7 @@ class CostModel:
         pseudo-format).
 
         ``n_model``: tensor-parallel shards — scales both byte terms to
-        the PER-CHIP stream (``HBM_BW`` is a per-chip bandwidth, so a
+        the PER-CHIP stream (HBM bandwidth is per chip, so a
         meshed engine seeded with global bytes would predict tick times
         ``n_model``x too slow and mis-rank the SLO tiers).
         """

@@ -25,26 +25,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.formats import MXFormat, get_format
 from repro.core.mx import MXTensor, dequantize, quantize
 
-try:                                       # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                        # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 PAD = 128   # flatten-pad multiple (>= block size, lane aligned)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True, **kw):
-    """Version-stable ``shard_map`` for wiring ``compressed_pod_allreduce``.
-
-    Newer JAX spells the replication check ``check_vma``; the experimental
-    API spells it ``check_rep``. Callers use the new spelling.
-    """
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma, **kw)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma, **kw)
 
 
 def _flatten_pad(g: jax.Array, bs: int) -> Tuple[jax.Array, int]:

@@ -8,9 +8,11 @@ import numpy as np
 
 from repro.train.compression import (compressed_bytes, ef_compress_leaf,
                                      compressed_pod_allreduce,
-                                     init_error_state, shard_map)
+                                     init_error_state)
+from jax import shard_map
 from repro.core.formats import get_format
 from repro.core.mx import dequantize
+from repro.launch.mesh import make_mesh
 
 
 def test_ef_compress_roundtrip_error_bounded():
@@ -57,7 +59,7 @@ def test_compressed_bytes_accounting():
 def test_pod_allreduce_shard_map_single_device():
     """Wire through shard_map on a pod-axis mesh of size 1 (CPU container);
     numerics = identity reduce + error feedback."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     grads = {"w": jnp.asarray(np.random.default_rng(2).normal(size=(64, 32)),
                               jnp.float32)}
     err = init_error_state(grads)

@@ -14,7 +14,6 @@ import pytest
 
 from repro.configs.shapes import ShapeSpec
 from repro.launch import costmodel as cm
-from repro.launch._compat import compiled_cost
 from repro.models import get_model
 from repro.models.common import ModelConfig
 
@@ -28,7 +27,7 @@ def _tiny_dense():
 
 def _compiled_flops(fn, *args):
     c = jax.jit(fn).lower(*args).compile()
-    return float(compiled_cost(c).get("flops", 0.0))
+    return float((c.cost_analysis() or {}).get("flops", 0.0))
 
 
 def test_prefill_flops_close():
@@ -213,8 +212,10 @@ def test_costmodel_from_roofline_per_chip_seed():
     from repro.configs import get_reduced
     from repro.serve.slo import CostModel
     cfg = get_reduced("smollm-135m")
-    c1 = CostModel.from_roofline(cfg, ("mxint8",), max_len=48)
-    c2 = CostModel.from_roofline(cfg, ("mxint8",), max_len=48, n_model=2)
+    c1 = CostModel.from_roofline(cfg, ("mxint8",), max_len=48,
+                                 hbm_bytes_per_s=1e9)
+    c2 = CostModel.from_roofline(cfg, ("mxint8",), max_len=48, n_model=2,
+                                 hbm_bytes_per_s=1e9)
     p1 = c1.raw_predict_s("mxint8", rows=2)
     p2 = c2.raw_predict_s("mxint8", rows=2)
     assert p1 is not None and p2 is not None
@@ -234,7 +235,7 @@ def test_meshed_engine_seeds_per_chip_bytes():
     cfg, eng1 = _serve_engine()
     _, eng2 = _serve_engine(
         mesh=make_debug_mesh(1, 2),
-        policy=FormatPolicy("mxint8", cost=CostModel()))
+        policy=FormatPolicy("mxint8", cost=CostModel(hbm_bytes_per_s=1e9)))
     for fmt in ("mxint8", "bf16"):
         eng1.weights_for(fmt)
         eng2.weights_for(fmt)

@@ -92,8 +92,9 @@ def test_qmatmul_batched_x_and_dtype():
 
 
 def test_qmatmul_legacy_splitk_leaf_falls_back():
-    """Split-K nibble layout has no fused kernel: pallas mode must silently
-    densify (and stay correct) rather than feed the kernel a wrong layout."""
+    """Split-K nibble layout has no fused kernel: mode "auto" densifies it
+    (and stays correct) rather than feed the kernel a wrong layout, while an
+    explicit mode "pallas" refuses it loudly instead of densifying unseen."""
     fmt = get_format("mxint4", 32)
     x = _rand((4, 64), seed=9)
     w = _rand((64, 96), seed=10)
@@ -101,7 +102,9 @@ def test_qmatmul_legacy_splitk_leaf_falls_back():
     leaf = pack_leaf_int4(t, layout="splitk")
     want = np.asarray(x @ dequantize(t, jnp.float32))
     dispatch.reset_stats()
-    got = np.asarray(dispatch.qmatmul(x, leaf, mode="pallas"))
+    with pytest.raises(ValueError, match="cannot take the fused kernel"):
+        dispatch.qmatmul(x, leaf, mode="pallas")
+    got = np.asarray(dispatch.qmatmul(x, leaf, mode="auto"))
     st = dispatch.stats()
     assert st["densify"] == 1 and st["pallas_int4"] == 0
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
@@ -129,9 +132,12 @@ def test_dispatch_counters_see_pallas_hits():
 def test_tile_registration_overrides_table():
     fmt = get_format("mxint8", 32)
     base = dispatch.select_tiles(7, 64, 96, fmt)
-    dispatch.register_tiles(7, 64, 96, "mxint8", (8, 48, 32))
+    # a Mosaic-legal entry the heuristic would not pick (N padded to a lane
+    # tile instead of taken whole)
+    dispatch.register_tiles(7, 64, 96, "mxint8", (8, 128, 64))
     try:
-        assert dispatch.select_tiles(7, 64, 96, fmt) == (8, 48, 32)
+        assert base != (8, 128, 64)
+        assert dispatch.select_tiles(7, 64, 96, fmt) == (8, 128, 64)
         # registered tiles actually run (and stay correct)
         x = _rand((7, 64), seed=13)
         w = _rand((64, 96), seed=14)
@@ -147,21 +153,21 @@ def test_tile_registration_overrides_table():
 
 def test_tile_cache_keys_on_block_size():
     """Regression (tensor-parallel serving PR): the tile cache must key on
-    block_size. An entry tuned at bs=64 (tk=64) applied to a bs=96 call
-    with the same (M, K, N) gives a tk that doesn't divide the scale
-    blocking — ``kp // bs`` truncates and the kernel reads wrong scales —
-    so cross-block-size hits must be misses."""
+    block_size. An entry tuned at bs=64 applied to a bs=96 call with the
+    same (M, K, N) can give a tk that doesn't divide the scale blocking —
+    ``kp // bs`` truncates and the kernel reads wrong scales — so
+    cross-block-size hits must be misses."""
     fmt64 = get_format("mxint8", 64)
     fmt32 = get_format("mxint8", 32)
-    dispatch.register_tiles(7, 192, 96, "mxint8", (8, 48, 64),
+    dispatch.register_tiles(7, 192, 96, "mxint8", (16, 128, 192),
                             block_size=64)
     try:
-        assert dispatch.select_tiles(7, 192, 96, fmt64) == (8, 48, 64)
+        assert dispatch.select_tiles(7, 192, 96, fmt64) == (16, 128, 192)
         # same (m, k, n), different block size: the bs=64 entry must NOT
         # apply — the key includes block_size, so the bs=32 lookup falls
         # back to the heuristic, whose tk always divides its own blocking.
         t32 = dispatch.select_tiles(7, 192, 96, fmt32)
-        assert t32 != (8, 48, 64)
+        assert t32 != (16, 128, 192)
         assert t32[2] % 32 == 0
     finally:
         dispatch._TILE_CACHE.pop((7, 192, 96, 64, "mxint8", "mx"), None)
@@ -178,9 +184,9 @@ def test_tile_cache_local_shard_shapes_hit_globals_miss():
     # global-shape registration with tiles that would NOT divide the local
     # shard (tn=256 > n_local): must not leak into the local-shape lookup
     dispatch.register_tiles(8, 64, n_global, "mxint8", (8, 256, 64))
-    dispatch.register_tiles(8, 64, n_local, "mxint8", (8, 64, 32))
+    dispatch.register_tiles(8, 64, n_local, "mxint8", (16, 128, 64))
     try:
-        assert dispatch.select_tiles(8, 64, n_local, fmt) == (8, 64, 32)
+        assert dispatch.select_tiles(8, 64, n_local, fmt) == (16, 128, 64)
         assert dispatch.select_tiles(8, 64, n_global, fmt) == (8, 256, 64)
         # the registered local tiles actually run on a local-shaped GEMM
         x = _rand((8, 64), seed=18)
@@ -221,6 +227,40 @@ def test_select_tiles_divide_padded_dims():
             assert -(-m // tm) * tm < m + tm
             assert -(-n_eff // tn) * tn < n_eff + tn
             assert -(-k // tk) * tk < k + tk
+
+
+@pytest.mark.parametrize("bs", [16, 32, 64])
+@pytest.mark.parametrize("name", ["mxint8", "mxint4"])
+@pytest.mark.parametrize("mkn", [(8, 576, 576), (8, 576, 192), (8, 1536, 576),
+                                 (8, 2560, 9728), (8, 9728, 2560),
+                                 (1, 64, 96), (300, 544, 1000), (8, 96, 130)])
+def test_select_tiles_meet_mosaic_block_rules(mkn, name, bs):
+    """The (8, 128) tiling rule, stated on its own: the (tm, tk) activation
+    block and (tk, tn) weight block tile as lane multiples or whole dims, and
+    the (tk/bs, tn) scale block's row count is a sublane multiple unless tk
+    is the whole K. Real widths (K=576) used to get tk=288 here."""
+    m, k, n = mkn
+    fmt = get_format(name, bs)
+    kind = "int4" if name == "mxint4" else "mx"
+    tm, tn, tk = dispatch.select_tiles(m, k, n, fmt, kind)
+    n_eff = n // 2 if kind == "int4" else n
+    k_whole = -(-k // bs) * bs
+    assert tm % 8 == 0
+    assert tn % 128 == 0 or (kind == "mx" and tn == n_eff)
+    assert tk == k_whole or (tk % 128 == 0 and (tk // bs) % 8 == 0)
+    assert tk % bs == 0
+
+
+def test_misaligned_registered_tiles_are_ignored():
+    """Entries that break the lane/sublane rules (tn=48, tk=32 at K=64) are
+    skipped — the heuristic's aligned tiles apply instead."""
+    fmt = get_format("mxint8", 32)
+    base = dispatch.select_tiles(8, 64, 96, fmt)
+    dispatch.register_tiles(8, 64, 96, "mxint8", (8, 48, 32))
+    try:
+        assert dispatch.select_tiles(8, 64, 96, fmt) == base
+    finally:
+        dispatch._TILE_CACHE.pop((8, 64, 96, 32, "mxint8", "mx"), None)
 
 
 def test_mode_resolution():

@@ -258,3 +258,17 @@ def test_replica_meshes_disjoint():
     assert len(set(devs)) == 2
     with pytest.raises(ValueError, match="device"):
         replica_meshes(2, 2)   # 4 needed, 2 present
+
+
+def test_prompt_logits_mesh_matches_single_device(setup):
+    """The logits-level twin of the stream identity tests: the meshed
+    engine's mixed step (psums inside shard_map) against one device."""
+    cfg, api, params, anchor = setup
+    kw = dict(batch_slots=2, max_len=48, param_template=params, fused=False,
+              kv_layout="paged", kv_page_size=8, prefill_chunk=8,
+              scheduler="mixed")
+    prompt = _reqs(cfg, n=1, plen=19)[0].prompt
+    single = ElasticEngine(api, anchor, **kw).prompt_logits(prompt, "mxint8")
+    meshed = ElasticEngine(api, anchor, mesh=make_debug_mesh(1, 2),
+                           **kw).prompt_logits(prompt, "mxint8")
+    np.testing.assert_allclose(meshed, single, rtol=1e-5, atol=1e-5)

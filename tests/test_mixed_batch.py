@@ -72,8 +72,8 @@ def _mq_case(seed, rows, ps=PS, c=8, hkv=2, g=2, d=16):
     mp = max(-(-(qo + ql) // ps) for qo, ql in rows)
     n_pages = b * mp + 1
     q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv * d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv * d)), jnp.float32)
     perm = rng.permutation(np.arange(1, n_pages))
     bt = np.zeros((b, mp), np.int32)
     for i, (qo, ql) in enumerate(rows):
@@ -90,7 +90,8 @@ def _mq_kernel(q, kp, vp, bt, qo, ql, window=None, tq=None):
 
 
 def _mq_gather_ref(q, kp, vp, bt, qo, ql, window=None):
-    return mixed_attention(q, paged_gather(kp, bt), paged_gather(vp, bt),
+    return mixed_attention(q, paged_gather(kp, bt, q.shape[-1]),
+                            paged_gather(vp, bt, q.shape[-1]),
                            qo, ql, window=window)
 
 
@@ -246,7 +247,7 @@ def test_mixed_step_logits_survive_poisoned_pool():
     for blk in cache["blocks"]:
         mask = np.asarray([pg not in used
                            for pg in range(blk["k_pages"].shape[1])])
-        sel = jnp.asarray(mask)[None, :, None, None, None]
+        sel = jnp.asarray(mask)[None, :, None, None]
         poisoned["blocks"].append({
             "k_pages": jnp.where(sel, jnp.asarray(
                 jnp.nan, blk["k_pages"].dtype), blk["k_pages"]),
@@ -461,3 +462,32 @@ def test_execs_per_tick_invariant_survives_speculation(setup):
         # keep the coalesced single-exec shape with no draft burst
         if t["prefill_chunks"]:
             assert t["draft_execs"] == 0 and t["verify_execs"] == 0, t
+
+
+@pytest.mark.parametrize("fmt", ["mxint8", "mxint4"])
+def test_prompt_logits_agree_across_contracts(setup, fmt):
+    """prompt_logits runs the engine's own mixed-step executable: the fused
+    dequant-GEMM + MQ paged kernel and the densify + gather reference give
+    the same next-token logits, and their argmax is the first token greedy
+    generate() serves for that prompt (a prompt spanning several chunks)."""
+    cfg, api, params, anchor = setup
+    prompt = _reqs(cfg, 2, plens=(21,))[1].prompt
+    kw = dict(kv_layout="paged", kv_page_size=PS, prefill_chunk=CHUNK,
+              scheduler="mixed")
+    fused = _engine(api, anchor, params, fused=True,
+                    attn_impl="paged_kernel", **kw)
+    ref = _engine(api, anchor, params, fused=False, attn_impl="gather", **kw)
+    got = fused.prompt_logits(prompt, fmt)
+    want = ref.prompt_logits(prompt, fmt)
+    assert got.shape == (cfg.vocab,) and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    req = Request(rid=0, prompt=prompt, max_new=1)
+    fused.generate([req], fmt_override=fmt)
+    assert req.out_tokens == [int(np.argmax(got))]
+
+
+def test_prompt_logits_needs_mixed_paged_engine(setup):
+    cfg, api, params, anchor = setup
+    eng = _engine(api, anchor, params, fused=False)
+    with pytest.raises(ValueError, match="scheduler='mixed'"):
+        eng.prompt_logits(np.arange(1, 9, dtype=np.int32), "mxint8")

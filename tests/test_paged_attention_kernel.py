@@ -57,8 +57,8 @@ def _pool_case(seed, b, mp, ps, hkv, g, d=16):
     h = hkv * g
     n_pages = b * mp + 1
     q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv * d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv * d)), jnp.float32)
     perm = rng.permutation(np.arange(1, n_pages))
     bt = np.zeros((b, mp), np.int32)
     return q, kp, vp, bt, perm
@@ -74,7 +74,8 @@ def _map_pages(bt, perm, lens, mp, ps):
 
 
 def _gather_ref(q, kp, vp, bt, cl, window=None):
-    return decode_attention(q, paged_gather(kp, bt), paged_gather(vp, bt),
+    return decode_attention(q, paged_gather(kp, bt, q.shape[-1]),
+                            paged_gather(vp, bt, q.shape[-1]),
                             cl, window=window)
 
 
@@ -222,7 +223,7 @@ def test_serve_step_logits_survive_poisoned_pool():
     for blk in cache["blocks"]:
         mask = np.asarray([pg not in used
                            for pg in range(blk["k_pages"].shape[1])])
-        sel = jnp.asarray(mask)[None, :, None, None, None]
+        sel = jnp.asarray(mask)[None, :, None, None]
         poisoned["blocks"].append({
             "k_pages": jnp.where(sel, jnp.asarray(
                 jnp.nan, blk["k_pages"].dtype), blk["k_pages"]),
@@ -259,8 +260,8 @@ def _check_roundtrip(seed, ps, lens):
 
     # paged: prefill-scatter the (padded) prompt, then append one token at
     # each row's cache_len — exactly what a decode tick does.
-    kp = paged_prefill_update(jnp.zeros((n_pages, ps, hkv, d)), k_new, bt)
-    vp = paged_prefill_update(jnp.zeros((n_pages, ps, hkv, d)), v_new, bt)
+    kp = paged_prefill_update(jnp.zeros((n_pages, ps, hkv * d)), k_new, bt)
+    vp = paged_prefill_update(jnp.zeros((n_pages, ps, hkv * d)), v_new, bt)
     kp = paged_decode_append(kp, k_tok, bt, cl)
     vp = paged_decode_append(vp, v_tok, bt, cl)
 

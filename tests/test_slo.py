@@ -247,7 +247,8 @@ def test_stats_expose_cost_model_and_admission_order():
     pol = FormatPolicy(anchor="mxint8",
                        ladder=((6, "mxint4"), (0, "mxint8")),
                        cost=CostModel.from_roofline(
-                           cfg, ("mxint4", "mxint8"), max_len=48))
+                           cfg, ("mxint4", "mxint8"), max_len=48,
+                           hbm_bytes_per_s=1e9))
     seeded_base = pol.cost.terms["mxint8"].base_s
     _, eng = _engine(slots=2, policy=pol, admission_order="slo")
     reqs = [_req(cfg, i, max_new=6,
