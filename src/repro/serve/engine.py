@@ -77,8 +77,10 @@ state at the next tick boundary (``checkpoint.io.save_flat``) so
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -192,6 +194,9 @@ class Request:
     #                                     against the SLO is ttft_s minus
     #                                     this)
     admitted_tick: Optional[int] = None  # tick admission claimed it
+    admitted_s: Optional[float] = None   # engine clock (as arrival_s) when
+    #                                      admission claimed it; a requeue
+    #                                      for pages re-stamps it
     temperature: Optional[float] = None  # None -> engine default
     top_p: Optional[float] = None        # None -> engine default
 
@@ -532,7 +537,13 @@ class ElasticEngine:
         self._admission_requeues = 0
         self._fmt_decode_ticks: Dict[str, int] = {}  # clean decode ticks
         #                          per format (cost-model compile warmup)
-        self.tick_trace: List[Dict[str, float]] = []   # reset per generate
+        self.tick_trace: List[dict] = []   # reset per generate
+        # The engine clock's origin (generate's start) and the open tick's
+        # record of phases and stamps (_phase; None between ticks).
+        self._t0 = time.perf_counter()
+        self._tick_rec: Optional[dict] = None
+        self._in_phase = False
+        self._step_t: Optional[float] = None  # dispatch awaiting its fetch
         self._kv_pages_alloc = 0
         self._kv_pages_freed = 0
         self._kv_pages_hwm = 0
@@ -570,7 +581,7 @@ class ElasticEngine:
         # Per-slot RNG: reseeded from (engine key, rid) at admission.
         self._key = jax.random.PRNGKey(seed)
         self._slot_keys = jax.random.split(self._key, self.slots)
-        self._prefill_traces = 0     # host-side compile counter (bucketing)
+        self._traces: Dict[str, int] = {}  # compiles per counted step name
         # Jitted entry points. Dense and packed trees have different pytree
         # structures, so jit caches one executable per cached format. The
         # decode steps bake attn_impl in at build time (same rationale as
@@ -647,9 +658,14 @@ class ElasticEngine:
             lambda lg: jnp.isfinite(lg).all(axis=(-2, -1)))
 
     def _counting(self, fn):
-        """Wrap a to-be-jitted fn so traces (= compiles) are counted."""
+        """Wrap a to-be-jitted fn so its traces (= compiles) are counted
+        under its name, which the wrapper keeps: the executable compiles
+        as ``jit_<name>`` (``jit_mixed_step``), as a profiler shows it."""
+        name = fn.__name__
+
+        @functools.wraps(fn)
         def wrapped(*args):
-            self._prefill_traces += 1    # runs at trace time only
+            self._traces[name] = self._traces.get(name, 0) + 1  # at trace
             return fn(*args)
         return wrapped
 
@@ -749,20 +765,21 @@ class ElasticEngine:
         packing at 4 bits); hits are free.
         """
         if fmt_name not in self._weights:
-            if self._serves_packed(fmt_name):
-                w = make_packed_params(self.anchor, self._template,
-                                       target_fmt=fmt_name,
-                                       dtype=self.api.cfg.compute_dtype)
-            else:
-                w = self.dense_weights_for(fmt_name)
-            if self.mesh is not None:
-                shardings = self._weight_shardings(w)
-                # split-N int4 nibbles interleave the output halves; a
-                # column-sharded leaf must be repacked per shard first
-                # (see repack_splitn_for_tp) or half the head / ff-block
-                # contributions pair wrong inside shard_map.
-                w = repack_splitn_for_tp(w, shardings, self._tp)
-                w = jax.device_put(w, shardings)
+            with self._phase("convert", fmt=fmt_name):
+                if self._serves_packed(fmt_name):
+                    w = make_packed_params(self.anchor, self._template,
+                                           target_fmt=fmt_name,
+                                           dtype=self.api.cfg.compute_dtype)
+                else:
+                    w = self.dense_weights_for(fmt_name)
+                if self.mesh is not None:
+                    shardings = self._weight_shardings(w)
+                    # split-N int4 nibbles interleave the output halves; a
+                    # column-sharded leaf must be repacked per shard first
+                    # (see repack_splitn_for_tp) or half the head /
+                    # ff-block contributions pair wrong inside shard_map.
+                    w = repack_splitn_for_tp(w, shardings, self._tp)
+                    w = jax.device_put(w, shardings)
             self._weights[fmt_name] = w
             self._fmt_swaps += 1
             if self.policy.cost is not None:
@@ -943,6 +960,7 @@ class ElasticEngine:
             if reason is None:
                 if tick is not None:
                     r.admitted_tick = tick
+                    r.admitted_s = time.perf_counter() - self._t0
                 return r
             self._finish(r, RequestStatus.FAILED_CAPACITY, reason)
 
@@ -989,19 +1007,25 @@ class ElasticEngine:
         self.set_format(nxt)
         return nxt
 
-    def _guarded_prefill(self, attempt, pinned: str, tick: int, what: str):
+    def _guarded_prefill(self, attempt, pinned: str, tick: int, what: str,
+                         kind: str):
         """Numeric guardrail around one admission executable (a monolithic
         prompt or a final chunk — the ones whose logits are consumed).
         Escalate-and-replay until finite or at the anchor; each attempt is
         a pure function of the pre-tick cache, so replays are safe.
+        ``kind`` (``prefill`` | ``chunk``) names the attempts' dispatches.
         Returns ``(logits, cache, new_len, pinned, fail_reason, execs)``.
         """
         execs = 0
         while True:
-            logits, cache2, new_len = attempt(pinned)
+            with self._phase("dispatch", kind=kind, fmt=pinned):
+                logits, cache2, new_len = attempt(pinned)
             execs += 1
-            if not self.logit_guard or \
-                    bool(np.asarray(self._finite_rows(logits))):
+            if not self.logit_guard:
+                return logits, cache2, new_len, pinned, None, execs
+            with self._phase("fetch", what="guard"):
+                finite = bool(np.asarray(self._finite_rows(logits)))
+            if finite:
                 return logits, cache2, new_len, pinned, None, execs
             self._faults_detected += 1
             nxt = self._escalate_or_none(pinned, tick, what)
@@ -1013,7 +1037,7 @@ class ElasticEngine:
             self._ticks_replayed += 1
 
     def _guarded_decode(self, attempt, pinned: str, consumed: List[int],
-                        tick: int, finite_fn=None):
+                        tick: int, kind: str, finite_fn=None):
         """Run one decode/mixed/verify executable under the guardrail.
 
         Replay semantics (docs/serving_internals.md §7): every attempt is
@@ -1030,14 +1054,16 @@ class ElasticEngine:
         escalate the format one rung and replay; at the anchor the dead
         rows are returned for per-row retirement. ``finite_fn`` overrides
         the per-row finiteness reduction (the verify step's (B, C, V)
-        logits reduce the lane axis too).
+        logits reduce the lane axis too). ``kind`` (``decode`` | ``mixed``
+        | ``verify``) names the attempts' dispatches.
         Returns ``(logits, cache, pinned, dead_rows, execs)``.
         """
         retries = 0
         execs = 0
         while True:
             try:
-                logits, cache2 = attempt(pinned)
+                with self._phase("dispatch", kind=kind, fmt=pinned):
+                    logits, cache2 = attempt(pinned)
                 execs += 1
             except InjectedFault:
                 self._faults_detected += 1
@@ -1048,7 +1074,8 @@ class ElasticEngine:
                 continue
             if not self.logit_guard or not consumed:
                 return logits, cache2, pinned, [], execs
-            finite = np.asarray((finite_fn or self._finite_rows)(logits))
+            with self._phase("fetch", what="guard"):
+                finite = np.asarray((finite_fn or self._finite_rows)(logits))
             dead = [i for i in consumed if not finite[i]]
             if not dead:
                 return logits, cache2, pinned, [], execs
@@ -1073,7 +1100,8 @@ class ElasticEngine:
         each scheduler tick runs at most one prefill chunk before the
         batched decode step; ``tick_trace`` records the per-tick work so
         that bound is testable, and each ``Request.ttft_s`` is stamped when
-        its first token is sampled.
+        its first token is sampled. Each tick is a profiler span over its
+        host phases (``_phase``; docs/serving_internals.md §6).
 
         Fault isolation (docs/serving_internals.md §7): per-request faults
         (oversized prompt, deadline, cancellation, capacity starvation,
@@ -1142,6 +1170,7 @@ class ElasticEngine:
             elapsed0 = _state["elapsed_s"]
             tick_no = _state["tick_no"]
         t0 = time.perf_counter() - elapsed0  # deadline clock spans resumes
+        self._t0 = t0
         self.tick_trace = []
 
         def repin(new_fmt: str) -> str:
@@ -1166,816 +1195,935 @@ class ElasticEngine:
             logits, stamp TTFT. Seeding happens HERE — at prefill
             completion, right before the first draw — so chunked admission
             (whose mid-prefill slots see decode ticks advance every slot
-            key) samples the same stream as monolithic."""
+            key) samples the same stream as monolithic. Called between
+            phases: it opens its own."""
             nonlocal tokens
-            self._slot_keys = self._slot_keys.at[i].set(
-                jax.random.fold_in(self._key, r.rid))
-            # Per-request sampling params land with the RNG reseed — before
-            # the first draw, so the whole stream (first token included)
-            # uses them.
-            self._slot_temp[i] = self.temperature \
-                if r.temperature is None else r.temperature
-            self._slot_topp[i] = self.top_p if r.top_p is None else r.top_p
-            first = int(self._sample(logits[None], greedy, slot=i)[0])
-            tokens = tokens.at[i, 0].set(first)
-            r.fmt_used = pinned            # pinned for the whole sequence
-            r.out_tokens.append(first)
-            r.ttft_s = time.perf_counter() - t0
-            self._tokens_out += 1
-            if len(r.out_tokens) >= r.max_new:
-                self._finish(r, RequestStatus.COMPLETED)  # max_new<=1
-                release_slot(i)            # row -> scratch BEFORE any reuse
-            else:
-                r.status = RequestStatus.RUNNING
-                active[i] = r
+            with self._phase("retire"):
+                self._slot_keys = self._slot_keys.at[i].set(
+                    jax.random.fold_in(self._key, r.rid))
+                # Per-request sampling params land with the RNG reseed —
+                # before the first draw, so the whole stream (first token
+                # included) uses them.
+                self._slot_temp[i] = self.temperature \
+                    if r.temperature is None else r.temperature
+                self._slot_topp[i] = self.top_p if r.top_p is None \
+                    else r.top_p
+                drawn = self._sample(logits[None], greedy, slot=i)[0]
+            with self._phase("fetch", what="first_token", rid=r.rid):
+                first = int(drawn)
+            with self._phase("retire"):
+                tokens = tokens.at[i, 0].set(first)
+                r.fmt_used = pinned            # pinned for the whole sequence
+                r.out_tokens.append(first)
+                r.ttft_s = time.perf_counter() - t0
+                self._tokens_out += 1
+                if len(r.out_tokens) >= r.max_new:
+                    self._finish(r, RequestStatus.COMPLETED)  # max_new<=1
+                    release_slot(i)        # row -> scratch BEFORE any reuse
+                else:
+                    r.status = RequestStatus.RUNNING
+                    active[i] = r
 
         while pending or filling is not None \
                 or any(a is not None for a in active):
             t_tick = time.perf_counter()
-            # ---- tick boundary: the atomic unit of fault handling. A
-            # preemption raised mid-tick (real signal or injector) is acted
-            # on HERE, with no executable in flight and host state
-            # consistent — snapshot and hand the wave back to the caller.
-            if guard is not None and guard.preempted:
-                if snapshot_dir is not None:
-                    self.last_snapshot = self._save_snapshot(
-                        snapshot_dir, requests, dict(
-                            pending=pending, active=active,
-                            slot_len=slot_len, cache=cache,
-                            cache_len=cache_len, tokens=tokens,
-                            pinned=pinned, filling=filling,
-                            fill_slot=fill_slot, fill_cursor=fill_cursor,
-                            wait_pages=wait_pages, free_pages=free_pages,
-                            bt=bt, elapsed_s=time.perf_counter() - t0,
-                            tick_no=tick_no),
-                        greedy, fmt_override)
-                    self._snapshots_saved += 1
-                return requests
-            tick = tick_no
-            tick_no += 1
-            # ---- per-request sweeps: cancellation (client- or injector-
-            # driven) and deadlines, across queued, mid-prefill, and
-            # decoding requests alike. Each hit is one terminal status and
-            # freed pages; nothing else in the batch is perturbed.
-            if fi is not None:
-                rid_cancel = fi.cancel_rid(tick)
-                if rid_cancel is not None:
-                    for r in pending + [a for a in active if a] + \
-                            ([filling] if filling is not None else []):
-                        if r.rid == rid_cancel:
-                            r.cancel_requested = True
-            now_elapsed = time.perf_counter() - t0
+            with self._phase("tick", tick=tick_no):
+                # ---- tick boundary: the atomic unit of fault handling. A
+                # preemption raised mid-tick (real signal or injector) is
+                # acted on HERE, with no executable in flight and host state
+                # consistent — snapshot and hand the wave back to the caller.
+                with self._phase("boundary"):
+                    preempted = guard is not None and guard.preempted
+                if preempted:
+                    if snapshot_dir is not None:
+                        with self._phase("fetch", what="snapshot"):
+                            self.last_snapshot = self._save_snapshot(
+                                snapshot_dir, requests, dict(
+                                    pending=pending, active=active,
+                                    slot_len=slot_len, cache=cache,
+                                    cache_len=cache_len, tokens=tokens,
+                                    pinned=pinned, filling=filling,
+                                    fill_slot=fill_slot,
+                                    fill_cursor=fill_cursor,
+                                    wait_pages=wait_pages,
+                                    free_pages=free_pages, bt=bt,
+                                    elapsed_s=time.perf_counter() - t0,
+                                    tick_no=tick_no),
+                                greedy, fmt_override)
+                        self._snapshots_saved += 1
+                    return requests
+                tick = tick_no
+                tick_no += 1
+                with self._phase("sweep"):
+                    # ---- per-request sweeps: cancellation (client- or
+                    # injector-driven) and deadlines, across queued,
+                    # mid-prefill, and decoding requests alike. Each hit is
+                    # one terminal status and freed pages; nothing else in
+                    # the batch is perturbed.
+                    if fi is not None:
+                        rid_cancel = fi.cancel_rid(tick)
+                        if rid_cancel is not None:
+                            for r in pending + [a for a in active if a] + \
+                                    ([filling] if filling is not None
+                                     else []):
+                                if r.rid == rid_cancel:
+                                    r.cancel_requested = True
+                    now_elapsed = time.perf_counter() - t0
 
-            def expired(r):
-                if r.cancel_requested:
-                    return RequestStatus.CANCELLED, "cancelled by client"
-                if r.deadline_s is not None and now_elapsed > r.deadline_s:
-                    return (RequestStatus.TIMED_OUT,
-                            f"deadline {r.deadline_s:.3f}s exceeded "
-                            f"({now_elapsed:.3f}s into the wave)")
-                return None
+                    def expired(r):
+                        if r.cancel_requested:
+                            return (RequestStatus.CANCELLED,
+                                    "cancelled by client")
+                        if r.deadline_s is not None \
+                                and now_elapsed > r.deadline_s:
+                            return (RequestStatus.TIMED_OUT,
+                                    f"deadline {r.deadline_s:.3f}s exceeded "
+                                    f"({now_elapsed:.3f}s into the wave)")
+                        return None
 
-            for r in list(pending):
-                if r.arrival_s is None and r.arrival_tick <= tick:
-                    r.arrival_s = now_elapsed   # came due this tick; SLO
-                    #                             TTFT counts from here
-                verdict = expired(r)
-                if verdict is not None:
-                    pending.remove(r)
-                    self._finish(r, *verdict)
-            if filling is not None:
-                verdict = expired(filling)
-                if verdict is not None:
-                    release_slot(fill_slot)
-                    self._finish(filling, *verdict)
-                    filling = None
-            for i, r in enumerate(active):
-                if r is None:
-                    continue
-                verdict = expired(r)
-                if verdict is not None:
-                    active[i] = None
-                    release_slot(i)
-                    self._finish(r, *verdict)
-            if not (pending or filling is not None
-                    or any(a is not None for a in active)):
-                break              # the sweep drained the wave
-            # Injected pool corruption lands before any executable runs.
-            if fi is not None and paged:
-                page = fi.pool_poison_page(tick)
-                if page is not None:
-                    cache = self._nan_pool_page(cache, page)
-
-            # ---- arrival gating: nothing live and every queued request
-            # still in the future (Request.arrival_tick) makes this an
-            # idle tick — record it and advance the clock so arrivals come
-            # due (the workload generator schedules in scheduler ticks).
-            if filling is None and not any(a is not None for a in active) \
-                    and not any(r.arrival_tick <= tick for r in pending):
-                pinned = None
-                self._record_tick(0, 0, 0, time.perf_counter() - t_tick,
-                                  execs=0, rows=0, decode_rows=0)
-                continue
-
-            if pinned is None:             # engine drained: re-pick format
-                # Load counts ARRIVED queued requests AND their pending
-                # prompt tokens, so a queue of long prompts downshifts
-                # before the admissions start, not after (serve/policy.py).
-                # With a cost model attached the wave's tightest TPOT
-                # budget and expected decode occupancy drive the pick
-                # instead (docs §10); fmt_override remains operator law.
-                arrived = [r for r in pending if r.arrival_tick <= tick]
-                pinned = self.policy.pick(
-                    queue_depth=len(arrived), active=0,
-                    prefill_tokens=sum(r.prompt.size for r in arrived),
-                    tpot_budget_ms=self._tightest_tpot_ms(arrived),
-                    decode_rows=max(1, min(b, len(arrived))),
-                    override=fmt_override)
-            self.set_format(pinned)
-            tick_pf_tokens = 0
-            tick_pf_chunks = 0
-            tick_execs = 0                 # executables dispatched this tick
-            tick_rows = 0                  # batch rows those executables ran
-            chunk_tok = None               # staged chunk for the mixed tick
-
-            if chunk is None:
-                # ---- monolithic admission: one whole prompt per free slot,
-                # active slots untouched (but stalled for the full prefill)
-                for i in range(b):
-                    if active[i] is not None or wait_pages:
-                        continue
-                    r = self._pop_admissible(pending, tick)
-                    if r is None:
-                        break
-                    r.status = RequestStatus.RUNNING
-                    prompt = np.asarray(r.prompt, np.int32)
-                    pbatch = self._prefill_batch(prompt)
-                    if paged:
-                        # Pages to hold the (possibly bucket-padded) prompt
-                        # AND the first decode write at position prompt.size.
-                        blen = pbatch["tokens"].shape[1]
-                        need = max(-(-blen // ps), prompt.size // ps + 1)
-                        try:
-                            got = self._alloc_pages(
-                                free_pages, need,
-                                f"admission of rid={r.rid}")
-                        except RuntimeError as e:
-                            # Admission never outranks running work: requeue
-                            # and wait for a retire to free pages (the
-                            # whole-pool check in _pop_admissible guarantees
-                            # the wait can end). An injected failure just
-                            # retries next tick; a real one with nothing
-                            # running means the free list leaked — raise.
-                            r.status = RequestStatus.QUEUED
-                            pending.insert(0, r)
-                            self._admission_requeues += 1
-                            if isinstance(e, InjectedFault):
-                                break
-                            if not any(a is not None for a in active):
-                                raise
-                            wait_pages = True
-                            break
-                        bt[i, :need] = got
-                        cache["block_table"] = jnp.asarray(bt)
-
-                    def attempt(fmt, pb=pbatch, slot=i):
-                        fn = self._packed_prefill_slot \
-                            if self._serves_packed(fmt) \
-                            else self._dense_prefill_slot
-                        lg, c2, nl = fn(self.weights_for(fmt), pb, cache,
-                                        slot)
-                        if fi is not None:
-                            lg = fi.maybe_poison_logits(tick, fmt, lg)
-                        return lg, c2, nl
-
-                    logits, cache, new_len, new_pinned, fail, execs = \
-                        self._guarded_prefill(attempt, pinned, tick,
-                                              f"prefill of rid={r.rid}")
-                    if new_pinned != pinned:
-                        pinned = repin(new_pinned)
-                    tick_pf_tokens += pbatch["tokens"].shape[1]
-                    tick_pf_chunks += 1
-                    tick_execs += execs
-                    tick_rows += execs
-                    if fail is not None:
-                        release_slot(i)
-                        self._finish(r, RequestStatus.FAILED_NUMERIC, fail)
-                        continue
-                    cache_len = cache_len.at[i].set(new_len)
-                    slot_len[i] = prompt.size
-                    complete_admission(i, r, logits)
-            else:
-                # ---- chunked admission bookkeeping: claim the (single)
-                # mid-prefill request and allocate THIS chunk's pages
-                # (release-and-requeue on exhaustion). Whether the staged
-                # chunk runs as its own executable or rides the decode batch
-                # is the scheduler's call, below.
-                if filling is None and not wait_pages and None in active:
-                    cand = self._pop_admissible(pending, tick)
-                    if cand is not None:
-                        fill_slot = active.index(None)
-                        filling, fill_cursor = cand, 0
-                        filling.status = RequestStatus.RUNNING
-                        # The mixed tick reads the fill row's cursor from
-                        # cache_len; zero the stale value from the slot's
-                        # previous occupant at claim time.
-                        cache_len = cache_len.at[fill_slot].set(0)
-                if filling is not None:
-                    r, i = filling, fill_slot
-                    prompt = np.asarray(r.prompt, np.int32)
-                    plen = prompt.size
-                    start = fill_cursor
-                    take = min(chunk, plen - start)
-                    final = start + take >= plen
-                    padded = take if (final and not self._bucket) else \
-                        (_bucket_len(take, chunk) if final else chunk)
-                    padded = min(padded, self.max_len - start)
-                    ok = True
-                    if paged:
-                        # This chunk's pages only — chunk N's pages are
-                        # allocated at chunk N, never all upfront. The first
-                        # decode write's page is the decode tick's job.
-                        first_pg = start // ps
-                        last_pg = -(-(start + padded) // ps)
-                        try:
-                            got = self._alloc_pages(
-                                free_pages, last_pg - first_pg,
-                                f"prefill chunk at {start} of rid={r.rid}")
-                        except RuntimeError as e:
-                            # Partial admission must not starve the pool:
-                            # release the pages already held, requeue, and
-                            # retry once a retire frees pages (injected
-                            # failures retry next tick without waiting).
-                            # With nothing running and a _pop_admissible-
-                            # sized prompt, only a leaked free list gets
-                            # here — re-raise.
-                            self._free_slot_pages(free_pages, bt, i)
-                            cache["block_table"] = jnp.asarray(bt)
-                            r.status = RequestStatus.QUEUED
-                            pending.insert(0, r)
+                    for r in list(pending):
+                        if r.arrival_s is None and r.arrival_tick <= tick:
+                            r.arrival_s = now_elapsed  # came due this tick;
+                            #                    SLO TTFT counts from here
+                        verdict = expired(r)
+                        if verdict is not None:
+                            pending.remove(r)
+                            self._finish(r, *verdict)
+                    if filling is not None:
+                        verdict = expired(filling)
+                        if verdict is not None:
+                            release_slot(fill_slot)
+                            self._finish(filling, *verdict)
                             filling = None
-                            self._admission_requeues += 1
-                            ok = False
-                            if isinstance(e, InjectedFault):
-                                pass       # transient: retry next tick
-                            elif any(a is not None for a in active):
-                                wait_pages = True
+                    for i, r in enumerate(active):
+                        if r is None:
+                            continue
+                        verdict = expired(r)
+                        if verdict is not None:
+                            active[i] = None
+                            release_slot(i)
+                            self._finish(r, *verdict)
+                    if not (pending or filling is not None
+                            or any(a is not None for a in active)):
+                        break          # the sweep drained the wave
+                    # Injected pool corruption lands before any executable
+                    # runs.
+                    if fi is not None and paged:
+                        page = fi.pool_poison_page(tick)
+                        if page is not None:
+                            cache = self._nan_pool_page(cache, page)
+
+                    # ---- arrival gating: nothing live and every queued
+                    # request still in the future (Request.arrival_tick)
+                    # makes this an idle tick — record it and advance the
+                    # clock so arrivals come due (the workload generator
+                    # schedules in scheduler ticks).
+                    idle = filling is None \
+                        and not any(a is not None for a in active) \
+                        and not any(r.arrival_tick <= tick for r in pending)
+                    if idle:
+                        pinned = None
+                    elif pinned is None:   # engine drained: re-pick format
+                        # Load counts ARRIVED queued requests AND their
+                        # pending prompt tokens, so a queue of long prompts
+                        # downshifts before the admissions start, not after
+                        # (serve/policy.py). With a cost model attached the
+                        # wave's tightest TPOT budget and expected decode
+                        # occupancy drive the pick instead (docs §10);
+                        # fmt_override remains operator law.
+                        arrived = [r for r in pending
+                                   if r.arrival_tick <= tick]
+                        pinned = self.policy.pick(
+                            queue_depth=len(arrived), active=0,
+                            prefill_tokens=sum(r.prompt.size
+                                               for r in arrived),
+                            tpot_budget_ms=self._tightest_tpot_ms(arrived),
+                            decode_rows=max(1, min(b, len(arrived))),
+                            override=fmt_override)
+                if idle:
+                    self._record_tick(0, 0, 0, time.perf_counter() - t_tick,
+                                      execs=0, rows=0, decode_rows=0)
+                    continue
+                self.set_format(pinned)    # a cache miss: engine.convert
+                tick_pf_tokens = 0
+                tick_pf_chunks = 0
+                tick_execs = 0             # executables dispatched this tick
+                tick_rows = 0              # batch rows those executables ran
+                chunk_tok = None           # staged chunk for the mixed tick
+
+                if chunk is None:
+                    # ---- monolithic admission: one whole prompt per free
+                    # slot, active slots untouched (but stalled for the full
+                    # prefill)
+                    for i in range(b):
+                        if active[i] is not None or wait_pages:
+                            continue
+                        with self._phase("admit") as span:
+                            r = self._pop_admissible(pending, tick)
+                            if r is None:
+                                break
+                            span.set_metadata(rid=r.rid)
+                            r.status = RequestStatus.RUNNING
+                            prompt = np.asarray(r.prompt, np.int32)
+                            pbatch = self._prefill_batch(prompt)
+                            if paged:
+                                # Pages to hold the (possibly bucket-padded)
+                                # prompt AND the first decode write at
+                                # position prompt.size.
+                                blen = pbatch["tokens"].shape[1]
+                                need = max(-(-blen // ps),
+                                           prompt.size // ps + 1)
+                                try:
+                                    got = self._alloc_pages(
+                                        free_pages, need,
+                                        f"admission of rid={r.rid}")
+                                except RuntimeError as e:
+                                    # Admission never outranks running
+                                    # work: requeue and wait for a retire to
+                                    # free pages (the whole-pool check in
+                                    # _pop_admissible guarantees the wait
+                                    # can end). An injected failure just
+                                    # retries next tick; a real one with
+                                    # nothing running means the free list
+                                    # leaked — raise.
+                                    r.status = RequestStatus.QUEUED
+                                    pending.insert(0, r)
+                                    self._admission_requeues += 1
+                                    if isinstance(e, InjectedFault):
+                                        break
+                                    if not any(a is not None for a in active):
+                                        raise
+                                    wait_pages = True
+                                    break
+                                bt[i, :need] = got
+                                cache["block_table"] = jnp.asarray(bt)
+
+                        def attempt(fmt, pb=pbatch, slot=i):
+                            fn = self._packed_prefill_slot \
+                                if self._serves_packed(fmt) \
+                                else self._dense_prefill_slot
+                            lg, c2, nl = fn(self.weights_for(fmt), pb, cache,
+                                            slot)
+                            if fi is not None:
+                                lg = fi.maybe_poison_logits(tick, fmt, lg)
+                            return lg, c2, nl
+
+                        logits, cache, new_len, new_pinned, fail, execs = \
+                            self._guarded_prefill(attempt, pinned, tick,
+                                                  f"prefill of rid={r.rid}",
+                                                  "prefill")
+                        with self._phase("retire"):
+                            if new_pinned != pinned:
+                                pinned = repin(new_pinned)
+                            tick_pf_tokens += pbatch["tokens"].shape[1]
+                            tick_pf_chunks += 1
+                            tick_execs += execs
+                            tick_rows += execs
+                            if fail is not None:
+                                release_slot(i)
+                                self._finish(r, RequestStatus.FAILED_NUMERIC,
+                                             fail)
+                                continue
+                            cache_len = cache_len.at[i].set(new_len)
+                            slot_len[i] = prompt.size
+                        complete_admission(i, r, logits)
+                else:
+                    # ---- chunked admission bookkeeping: claim the (single)
+                    # mid-prefill request and allocate THIS chunk's pages
+                    # (release-and-requeue on exhaustion). Whether the
+                    # staged chunk runs as its own executable or rides the
+                    # decode batch is the scheduler's call, below.
+                    with self._phase("admit") as span:
+                        if filling is None and not wait_pages \
+                                and None in active:
+                            cand = self._pop_admissible(pending, tick)
+                            if cand is not None:
+                                fill_slot = active.index(None)
+                                filling, fill_cursor = cand, 0
+                                filling.status = RequestStatus.RUNNING
+                                # The mixed tick reads the fill row's cursor
+                                # from cache_len; zero the stale value from
+                                # the slot's previous occupant at claim
+                                # time.
+                                cache_len = cache_len.at[fill_slot].set(0)
+                        if filling is not None:
+                            r, i = filling, fill_slot
+                            span.set_metadata(rid=r.rid)
+                            prompt = np.asarray(r.prompt, np.int32)
+                            plen = prompt.size
+                            start = fill_cursor
+                            take = min(chunk, plen - start)
+                            final = start + take >= plen
+                            padded = take if (final and not self._bucket) \
+                                else (_bucket_len(take, chunk) if final
+                                      else chunk)
+                            padded = min(padded, self.max_len - start)
+                        if filling is not None and paged:
+                            # This chunk's pages only — chunk N's pages are
+                            # allocated at chunk N, never all upfront. The
+                            # first decode write's page is the decode
+                            # tick's job.
+                            first_pg = start // ps
+                            last_pg = -(-(start + padded) // ps)
+                            try:
+                                got = self._alloc_pages(
+                                    free_pages, last_pg - first_pg,
+                                    f"prefill chunk at {start} of "
+                                    f"rid={r.rid}")
+                            except RuntimeError as e:
+                                # Partial admission must not starve the
+                                # pool: release the pages already held,
+                                # requeue, and retry once a retire frees
+                                # pages (injected failures retry next tick
+                                # without waiting). With nothing running and
+                                # a _pop_admissible-sized prompt, only a
+                                # leaked free list gets here — re-raise.
+                                self._free_slot_pages(free_pages, bt, i)
+                                cache["block_table"] = jnp.asarray(bt)
+                                r.status = RequestStatus.QUEUED
+                                pending.insert(0, r)
+                                filling = None
+                                self._admission_requeues += 1
+                                if isinstance(e, InjectedFault):
+                                    pass   # transient: retry next tick
+                                elif any(a is not None for a in active):
+                                    wait_pages = True
+                                else:
+                                    raise
                             else:
-                                raise
-                        if ok:
-                            bt[i, first_pg:last_pg] = got
-                            cache["block_table"] = jnp.asarray(bt)
-                    if ok:
-                        ctoks = np.zeros(padded, np.int32)
-                        ctoks[:take] = prompt[start:start + take]
-                        chunk_tok = (start, take, padded, final)
+                                bt[i, first_pg:last_pg] = got
+                                cache["block_table"] = jnp.asarray(bt)
+                    if filling is not None:
+                        with self._phase("stage"):
+                            ctoks = np.zeros(padded, np.int32)
+                            ctoks[:take] = prompt[start:start + take]
+                            chunk_tok = (start, take, padded, final)
 
-                # A staged chunk runs as its own executable under the
-                # sequential scheduler — and when no slot is decoding, where
-                # the two schedulers coincide (one executable either way,
-                # identical numerics).
-                chunk_ran_alone = False
-                if chunk_tok is not None and (
-                        self.scheduler == "sequential"
-                        or not any(a is not None for a in active)):
-                    chunk_ran_alone = True
-                    start, take, padded, final = chunk_tok
-                    pbatch = {"tokens": jnp.asarray(ctoks[None]),
-                              "lengths": jnp.asarray([plen], jnp.int32)}
+                    # A staged chunk runs as its own executable under the
+                    # sequential scheduler — and when no slot is decoding,
+                    # where the two schedulers coincide (one executable
+                    # either way, identical numerics).
+                    chunk_ran_alone = False
+                    if chunk_tok is not None and (
+                            self.scheduler == "sequential"
+                            or not any(a is not None for a in active)):
+                        chunk_ran_alone = True
+                        start, take, padded, final = chunk_tok
+                        with self._phase("stage"):
+                            pbatch = {"tokens": jnp.asarray(ctoks[None]),
+                                      "lengths": jnp.asarray([plen],
+                                                             jnp.int32)}
 
-                    def chunk_attempt(fmt, pb=pbatch, slot=i, st=start):
-                        fn = self._packed_prefill_chunk \
-                            if self._serves_packed(fmt) \
-                            else self._dense_prefill_chunk
-                        lg, c2, nl = fn(self.weights_for(fmt), pb, cache,
-                                        slot, st)
-                        if fi is not None:
-                            # A non-final chunk's logits are never consumed,
-                            # so a poison landing there is invisible — as a
-                            # real corruption of unread outputs would be.
-                            lg = fi.maybe_poison_logits(tick, fmt, lg)
-                        return lg, c2, nl
+                        def chunk_attempt(fmt, pb=pbatch, slot=i, st=start):
+                            fn = self._packed_prefill_chunk \
+                                if self._serves_packed(fmt) \
+                                else self._dense_prefill_chunk
+                            lg, c2, nl = fn(self.weights_for(fmt), pb, cache,
+                                            slot, st)
+                            if fi is not None:
+                                # A non-final chunk's logits are never
+                                # consumed, so a poison landing there is
+                                # invisible — as a real corruption of
+                                # unread outputs would be.
+                                lg = fi.maybe_poison_logits(tick, fmt, lg)
+                            return lg, c2, nl
 
-                    if final:
-                        # Only the final chunk's logits are consumed (they
-                        # seed the first sampled token) — guard them.
-                        (logits, cache, new_len, new_pinned, fail,
-                         execs) = self._guarded_prefill(
-                             chunk_attempt, pinned, tick,
-                             f"final chunk of rid={r.rid}")
-                        if new_pinned != pinned:
-                            pinned = repin(new_pinned)
-                    else:
-                        logits, cache, new_len = chunk_attempt(pinned)
-                        fail, execs = None, 1
-                    tick_pf_tokens += padded
-                    tick_pf_chunks += 1
-                    tick_execs += execs
-                    tick_rows += execs
-                    if fail is not None:
-                        release_slot(i)
-                        self._finish(r, RequestStatus.FAILED_NUMERIC, fail)
-                        filling = None
-                    else:
-                        cache_len = cache_len.at[i].set(new_len)
-                        fill_cursor = start + take
                         if final:
-                            slot_len[i] = plen
+                            # Only the final chunk's logits are consumed
+                            # (they seed the first sampled token) — guard
+                            # them.
+                            (logits, cache, new_len, new_pinned, fail,
+                             execs) = self._guarded_prefill(
+                                 chunk_attempt, pinned, tick,
+                                 f"final chunk of rid={r.rid}", "chunk")
+                        else:
+                            with self._phase("dispatch", kind="chunk",
+                                             fmt=pinned):
+                                logits, cache, new_len = \
+                                    chunk_attempt(pinned)
+                            new_pinned, fail, execs = pinned, None, 1
+                        with self._phase("retire"):
+                            if new_pinned != pinned:
+                                pinned = repin(new_pinned)
+                            tick_pf_tokens += padded
+                            tick_pf_chunks += 1
+                            tick_execs += execs
+                            tick_rows += execs
+                            if fail is not None:
+                                release_slot(i)
+                                self._finish(r, RequestStatus.FAILED_NUMERIC,
+                                             fail)
+                                filling = None
+                            else:
+                                cache_len = cache_len.at[i].set(new_len)
+                                fill_cursor = start + take
+                                if final:
+                                    slot_len[i] = plen
+                        if fail is None and final:
                             complete_admission(i, r, logits)
                             filling = None
-                    chunk_tok = None
+                        chunk_tok = None
 
-            # Injected preemption fires mid-tick; the guard's flag is acted
-            # on at the NEXT tick boundary, exactly like a real signal.
-            if fi is not None and guard is not None:
-                fi.maybe_preempt(tick, guard)
+                # Injected preemption fires mid-tick; the guard's flag is
+                # acted on at the NEXT tick boundary, exactly like a real
+                # signal.
+                if fi is not None and guard is not None:
+                    fi.maybe_preempt(tick, guard)
 
-            all_free = all(a is None for a in active)
-            if all_free or (chunk is not None and chunk_ran_alone
-                            and self.scheduler == "mixed"):
-                # No decode this tick. Under the mixed scheduler a chunk
-                # that ran alone ends the tick even when it just completed
-                # admission — the new slot's first decode is next tick's
-                # (one) executable, never a second one on this tick. The
-                # slot's stream is unchanged: its key advances once per
-                # decode tick it sits in, wherever that tick falls.
-                self._record_tick(tick_pf_tokens, tick_pf_chunks, 0,
-                                  time.perf_counter() - t_tick,
-                                  execs=tick_execs, rows=tick_rows,
-                                  decode_rows=0)
-                if all_free and filling is None:
-                    pinned = None          # drained; next wave re-picks
-                continue
+                all_free = all(a is None for a in active)
+                if all_free or (chunk is not None and chunk_ran_alone
+                                and self.scheduler == "mixed"):
+                    # No decode this tick. Under the mixed scheduler a chunk
+                    # that ran alone ends the tick even when it just
+                    # completed admission — the new slot's first decode is
+                    # next tick's (one) executable, never a second one on
+                    # this tick. The slot's stream is unchanged: its key
+                    # advances once per decode tick it sits in, wherever
+                    # that tick falls.
+                    self._record_tick(tick_pf_tokens, tick_pf_chunks, 0,
+                                      time.perf_counter() - t_tick,
+                                      execs=tick_execs, rows=tick_rows,
+                                      decode_rows=0)
+                    if all_free and filling is None:
+                        pinned = None      # drained; next wave re-picks
+                    continue
 
-            # ---- decode tick: fused step over all slots; free and
-            # mid-prefill slots are masked (their cache_len doesn't advance
-            # and their sampled tokens are dropped)
-            if paged:
-                # Map the page each active slot's write position lands in
-                # BEFORE the step runs — this is where the pool grows (and
-                # where exhaustion surfaces, contained, mid-stream).
-                dirty = False
-                for i in range(b):
-                    r = active[i]
-                    if r is None:
-                        continue
-                    pg = slot_len[i] // ps
-                    while active[i] is not None and bt[i, pg] == 0:
-                        try:
-                            got = self._alloc_pages(
-                                free_pages, 1,
-                                f"decode tick for rid={r.rid}")
-                            bt[i, pg] = got[0]
-                            dirty = True
-                        except RuntimeError as e:
-                            dirty = True
-                            if filling is not None:
-                                # A decoding slot outranks a partial
-                                # admission: release the mid-prefill slot's
-                                # pages (this tick's staged chunk included),
-                                # requeue it, and retry. Restarting the
-                                # admission from chunk 0 later cannot
-                                # perturb its stream (the slot RNG seeds at
-                                # prefill completion).
-                                self._free_slot_pages(free_pages, bt,
-                                                      fill_slot)
-                                filling.status = RequestStatus.QUEUED
-                                pending.insert(0, filling)
-                                filling = None
-                                chunk_tok = None
-                                self._admission_requeues += 1
-                                wait_pages = True
+                # ---- decode tick: fused step over all slots; free and
+                # mid-prefill slots are masked (their cache_len doesn't
+                # advance and their sampled tokens are dropped)
+                with self._phase("stage"):
+                    if paged:
+                        # Map the page each active slot's write position
+                        # lands in BEFORE the step runs — this is where the
+                        # pool grows (and where exhaustion surfaces,
+                        # contained, mid-stream).
+                        dirty = False
+                        for i in range(b):
+                            r = active[i]
+                            if r is None:
                                 continue
-                            # No admission to roll back: the largest page-
-                            # holder retires FAILED_CAPACITY and the engine
-                            # keeps serving the rest — the pre-PR 7
-                            # behavior (raise) destroyed every in-flight
-                            # stream. The victim may be this very slot.
-                            victim = self._capacity_victim(active, bt)
-                            if victim is None:
-                                raise      # free-list invariant breach
-                            vr = active[victim]
-                            held = int((bt[victim] != 0).sum())
-                            active[victim] = None
-                            self._free_slot_pages(free_pages, bt, victim)
-                            wait_pages = False
-                            self._finish(
-                                vr, RequestStatus.FAILED_CAPACITY,
-                                f"KV pool exhausted at decode; retired as "
-                                f"largest page-holder ({held} page(s)) "
-                                f"after {len(vr.out_tokens)} token(s): {e}")
-                if dirty:
-                    cache["block_table"] = jnp.asarray(bt)
-            if chunk_tok is None and all(a is None for a in active):
-                # Victim retirement emptied the batch; nothing left to run
-                # this tick. Survivors-to-be (queued work) admit next tick.
-                self._record_tick(tick_pf_tokens, tick_pf_chunks, 0,
-                                  time.perf_counter() - t_tick,
-                                  execs=tick_execs, rows=tick_rows,
-                                  decode_rows=0)
-                if filling is None:
-                    pinned = None
-                continue
+                            pg = slot_len[i] // ps
+                            while active[i] is not None and bt[i, pg] == 0:
+                                try:
+                                    got = self._alloc_pages(
+                                        free_pages, 1,
+                                        f"decode tick for rid={r.rid}")
+                                    bt[i, pg] = got[0]
+                                    dirty = True
+                                except RuntimeError as e:
+                                    dirty = True
+                                    if filling is not None:
+                                        # A decoding slot outranks a partial
+                                        # admission: release the mid-prefill
+                                        # slot's pages (this tick's staged
+                                        # chunk included), requeue it, and
+                                        # retry. Restarting the admission
+                                        # from chunk 0 later cannot perturb
+                                        # its stream (the slot RNG seeds at
+                                        # prefill completion).
+                                        self._free_slot_pages(free_pages, bt,
+                                                              fill_slot)
+                                        filling.status = RequestStatus.QUEUED
+                                        pending.insert(0, filling)
+                                        filling = None
+                                        chunk_tok = None
+                                        self._admission_requeues += 1
+                                        wait_pages = True
+                                        continue
+                                    # No admission to roll back: the largest
+                                    # page-holder retires FAILED_CAPACITY
+                                    # and the engine keeps serving the rest
+                                    # (raising instead would destroy every
+                                    # in-flight stream). The victim may be
+                                    # this very slot.
+                                    victim = self._capacity_victim(active, bt)
+                                    if victim is None:
+                                        raise  # free-list invariant breach
+                                    vr = active[victim]
+                                    held = int((bt[victim] != 0).sum())
+                                    active[victim] = None
+                                    self._free_slot_pages(free_pages, bt,
+                                                          victim)
+                                    wait_pages = False
+                                    self._finish(
+                                        vr, RequestStatus.FAILED_CAPACITY,
+                                        f"KV pool exhausted at decode; "
+                                        f"retired as largest page-holder "
+                                        f"({held} page(s)) after "
+                                        f"{len(vr.out_tokens)} token(s): {e}")
+                        if dirty:
+                            cache["block_table"] = jnp.asarray(bt)
+                if chunk_tok is None and all(a is None for a in active):
+                    # Victim retirement emptied the batch; nothing left to
+                    # run this tick. Survivors-to-be (queued work) admit
+                    # next tick.
+                    self._record_tick(tick_pf_tokens, tick_pf_chunks, 0,
+                                      time.perf_counter() - t_tick,
+                                      execs=tick_execs, rows=tick_rows,
+                                      decode_rows=0)
+                    if filling is None:
+                        pinned = None
+                    continue
 
-            mask = np.asarray([a is not None for a in active], np.int32)
-            # Rows whose logits this tick actually consumes — the guard
-            # checks exactly these (free/masked rows may hold garbage).
-            consumed = [i for i in range(b) if active[i] is not None]
-            if chunk_tok is not None and chunk_tok[3] \
-                    and filling is not None:
-                consumed.append(fill_slot)
+                with self._phase("stage"):
+                    mask = np.asarray([a is not None for a in active],
+                                      np.int32)
+                    # Rows whose logits this tick actually consumes — the
+                    # guard checks exactly these (free/masked rows may hold
+                    # garbage).
+                    consumed = [i for i in range(b) if active[i] is not None]
+                    if chunk_tok is not None and chunk_tok[3] \
+                            and filling is not None:
+                        consumed.append(fill_slot)
 
-            # ---- speculative decode tick (docs/serving_internals.md §9):
-            # k draft steps at the cheap rung against a LOCAL cursor, one
-            # batched pinned-format verify over the k+1 positions, commit
-            # the longest greedy-matching prefix + bonus token per slot,
-            # rewind the rest. Only on pure-decode ticks (no staged chunk),
-            # and only while the policy says drafting pays for itself.
-            sc = self.speculative
-            spec_now = sc is not None and chunk_tok is None and bool(consumed)
-            if spec_now:
-                tot = self._spec_accepted + self._spec_rejected
-                rate = (self._spec_accepted / tot
-                        if self._spec_ticks >= sc.window and tot else None)
-                spec_now = self.policy.allow_speculation(
-                    sc.draft_fmt, pinned, rate, sc.min_acceptance)
-            if spec_now:
-                # Burst length this tick: never write past the cache (the
-                # verify write frontier is slot_len + k_eff <= max_len - 1)
-                # and never draft deeper than the hungriest slot can still
-                # commit (budget - 1 drafts + the bonus token).
-                buds = {i: min(active[i].max_new
-                               - len(active[i].out_tokens),
-                               self.prompt_capacity - slot_len[i])
-                        for i in consumed}
-                k_eff = min(sc.k,
-                            self.max_len - 1
-                            - max(slot_len[i] for i in consumed),
-                            max(buds.values()) - 1)
-                spec_now = k_eff >= 1
-            if spec_now and paged:
-                # Draft-ahead pages covering positions slot_len..slot_len +
-                # k_eff per slot, ON TOP of the plain-decode page the loop
-                # above already mapped. Speculation never outranks anything:
-                # starvation hands the pages back and runs a plain tick.
-                spec_extra = []
-                try:
-                    for i in consumed:
-                        base_pg = slot_len[i] // ps
-                        for pg in range(base_pg + 1,
-                                        (slot_len[i] + k_eff) // ps + 1):
-                            if bt[i, pg] == 0:
-                                bt[i, pg] = self._alloc_pages(
-                                    free_pages, 1,
-                                    f"spec draft-ahead for "
-                                    f"rid={active[i].rid}")[0]
-                                spec_extra.append((i, pg))
-                except RuntimeError:
-                    for i, pg in spec_extra:
-                        free_pages.append(int(bt[i, pg]))
-                        bt[i, pg] = 0
-                        self._kv_pages_freed += 1
-                    spec_extra = []
-                    self._spec_aborts += 1
-                    spec_now = False
-                if spec_extra:
-                    cache["block_table"] = jnp.asarray(bt)
-            if spec_now:
-                # ---- draft phase: k_eff greedy serve_steps at draft_fmt.
-                # The committed (cache_len, tokens) never advance — local
-                # copies do — so abandoning the burst at any point needs no
-                # undo: draft KV sits past every committed cursor, masked,
-                # and the next write there overwrites it.
-                adv = jnp.asarray(mask)
-                loc_len, loc_tok = cache_len, tokens
-                drafts = np.zeros((b, k_eff), np.int64)
-                draft_execs = 0
-                draft_ok = True
-                for j in range(k_eff):
-                    try:
-                        if fi is not None:
-                            fi.maybe_raise_step(tick)
-                        fn = self._packed_step \
-                            if self._serves_packed(sc.draft_fmt) \
-                            else self._dense_step
-                        lg, cache = fn(self.weights_for(sc.draft_fmt),
-                                       {"tokens": loc_tok}, cache, loc_len)
-                        if fi is not None:
-                            lg = fi.maybe_poison_logits(tick, sc.draft_fmt,
-                                                        lg)
-                    except InjectedFault:
-                        # Transient crash mid-burst: drop the burst, decode
-                        # plain this tick (the injector fires once per tick,
-                        # so the plain attempt below runs clean).
-                        self._faults_detected += 1
-                        draft_ok = False
-                        break
-                    draft_execs += 1
-                    if self.logit_guard:
-                        finite = np.asarray(self._finite_rows(lg))
-                        if not all(finite[i] for i in consumed):
-                            # The draft rung itself is sick: quarantine it
-                            # (allow_speculation then vetoes the rest of
-                            # the wave — plain anchor-side decode from here
-                            # on) and abandon the burst. Nothing was
-                            # committed, so there is nothing to double-emit.
+                    # ---- speculative decode tick (docs/serving_internals.md
+                    # §9): k draft steps at the cheap rung against a LOCAL
+                    # cursor, one batched pinned-format verify over the k+1
+                    # positions, commit the longest greedy-matching prefix +
+                    # bonus token per slot, rewind the rest. Only on
+                    # pure-decode ticks (no staged chunk), and only while
+                    # the policy says drafting pays for itself.
+                    sc = self.speculative
+                    spec_now = sc is not None and chunk_tok is None \
+                        and bool(consumed)
+                    if spec_now:
+                        tot = self._spec_accepted + self._spec_rejected
+                        rate = (self._spec_accepted / tot
+                                if self._spec_ticks >= sc.window and tot
+                                else None)
+                        spec_now = self.policy.allow_speculation(
+                            sc.draft_fmt, pinned, rate, sc.min_acceptance)
+                    if spec_now:
+                        # Burst length this tick: never write past the cache
+                        # (the verify write frontier is slot_len + k_eff <=
+                        # max_len - 1) and never draft deeper than the
+                        # hungriest slot can still commit (budget - 1 drafts
+                        # + the bonus token).
+                        buds = {i: min(active[i].max_new
+                                       - len(active[i].out_tokens),
+                                       self.prompt_capacity - slot_len[i])
+                                for i in consumed}
+                        k_eff = min(sc.k,
+                                    self.max_len - 1
+                                    - max(slot_len[i] for i in consumed),
+                                    max(buds.values()) - 1)
+                        spec_now = k_eff >= 1
+                    if spec_now and paged:
+                        # Draft-ahead pages covering positions slot_len..
+                        # slot_len + k_eff per slot, ON TOP of the
+                        # plain-decode page the loop above already mapped.
+                        # Speculation never outranks anything: starvation
+                        # hands the pages back and runs a plain tick.
+                        spec_extra = []
+                        try:
+                            for i in consumed:
+                                base_pg = slot_len[i] // ps
+                                for pg in range(base_pg + 1,
+                                                (slot_len[i] + k_eff) // ps
+                                                + 1):
+                                    if bt[i, pg] == 0:
+                                        bt[i, pg] = self._alloc_pages(
+                                            free_pages, 1,
+                                            f"spec draft-ahead for "
+                                            f"rid={active[i].rid}")[0]
+                                        spec_extra.append((i, pg))
+                        except RuntimeError:
+                            for i, pg in spec_extra:
+                                free_pages.append(int(bt[i, pg]))
+                                bt[i, pg] = 0
+                                self._kv_pages_freed += 1
+                            spec_extra = []
+                            self._spec_aborts += 1
+                            spec_now = False
+                        if spec_extra:
+                            cache["block_table"] = jnp.asarray(bt)
+                if spec_now:
+                    # ---- draft phase: k_eff greedy serve_steps at
+                    # draft_fmt. The committed (cache_len, tokens) never
+                    # advance — local copies do — so abandoning the burst at
+                    # any point needs no undo: draft KV sits past every
+                    # committed cursor, masked, and the next write there
+                    # overwrites it.
+                    with self._phase("stage"):
+                        adv = jnp.asarray(mask)
+                        loc_len, loc_tok = cache_len, tokens
+                        drafts = np.zeros((b, k_eff), np.int64)
+                    draft_execs = 0
+                    draft_ok = True
+                    for j in range(k_eff):
+                        try:
+                            with self._phase("dispatch", kind="draft",
+                                             fmt=sc.draft_fmt):
+                                if fi is not None:
+                                    fi.maybe_raise_step(tick)
+                                fn = self._packed_step \
+                                    if self._serves_packed(sc.draft_fmt) \
+                                    else self._dense_step
+                                lg, cache = fn(
+                                    self.weights_for(sc.draft_fmt),
+                                    {"tokens": loc_tok}, cache, loc_len)
+                                if fi is not None:
+                                    lg = fi.maybe_poison_logits(
+                                        tick, sc.draft_fmt, lg)
+                                d = jnp.argmax(lg, -1)
+                        except InjectedFault:
+                            # Transient crash mid-burst: drop the burst,
+                            # decode plain this tick (the injector fires
+                            # once per tick, so the plain attempt below runs
+                            # clean).
                             self._faults_detected += 1
-                            self.policy.quarantine(sc.draft_fmt)
                             draft_ok = False
                             break
-                    d = jnp.argmax(lg, -1)
-                    drafts[:, j] = np.asarray(d)
-                    loc_tok = d[:, None].astype(jnp.int32)
-                    loc_len = loc_len + adv
-                if not draft_ok:
-                    self._spec_aborts += 1
-                    spec_now = False
-            if spec_now:
-                # ---- verify phase: ONE pinned-format executable scores
-                # [last committed token, d_1..d_k] per slot (q_len = k+1;
-                # masked rows ride at q_len 1 exactly as in a mixed tick).
-                # It writes pinned-format K/V over every draft-written
-                # position BEFORE attending, so each attempt is a pure
-                # function of committed state — _guarded_decode's
-                # escalate-and-replay applies unchanged, and the drafts are
-                # never re-run on a replay.
-                cdim = k_eff + 1
-                tok2d = jnp.zeros((b, cdim), jnp.int32) \
-                    .at[:, 0].set(tokens[:, 0]) \
-                    .at[:, 1:].set(jnp.asarray(drafts, jnp.int32))
-                q_np = np.ones(b, np.int32)
-                q_np[mask.astype(bool)] = cdim
-                batch_v = {"tokens": tok2d, "q_len": jnp.asarray(q_np)}
+                        draft_execs += 1
+                        if self.logit_guard:
+                            with self._phase("fetch", what="guard"):
+                                finite = np.asarray(self._finite_rows(lg))
+                            if not all(finite[i] for i in consumed):
+                                # The draft rung itself is sick: quarantine
+                                # it (allow_speculation then vetoes the rest
+                                # of the wave — plain anchor-side decode
+                                # from here on) and abandon the burst.
+                                # Nothing was committed, so there is nothing
+                                # to double-emit.
+                                self._faults_detected += 1
+                                self.policy.quarantine(sc.draft_fmt)
+                                draft_ok = False
+                                break
+                        with self._phase("fetch", what="draft"):
+                            drafts[:, j] = np.asarray(d)
+                        with self._phase("stage"):
+                            loc_tok = d[:, None].astype(jnp.int32)
+                            loc_len = loc_len + adv
+                    if not draft_ok:
+                        self._spec_aborts += 1
+                        spec_now = False
+                if spec_now:
+                    # ---- verify phase: ONE pinned-format executable scores
+                    # [last committed token, d_1..d_k] per slot (q_len =
+                    # k+1; masked rows ride at q_len 1 exactly as in a mixed
+                    # tick). It writes pinned-format K/V over every
+                    # draft-written position BEFORE attending, so each
+                    # attempt is a pure function of committed state —
+                    # _guarded_decode's escalate-and-replay applies
+                    # unchanged, and the drafts are never re-run on a
+                    # replay.
+                    with self._phase("stage"):
+                        cdim = k_eff + 1
+                        tok2d = jnp.zeros((b, cdim), jnp.int32) \
+                            .at[:, 0].set(tokens[:, 0]) \
+                            .at[:, 1:].set(jnp.asarray(drafts, jnp.int32))
+                        q_np = np.ones(b, np.int32)
+                        q_np[mask.astype(bool)] = cdim
+                        batch_v = {"tokens": tok2d,
+                                   "q_len": jnp.asarray(q_np)}
 
-                def vattempt(fmt, bv=batch_v):
-                    if fi is not None:
-                        fi.maybe_raise_step(tick)
-                    fn = self._packed_verify if self._serves_packed(fmt) \
-                        else self._dense_verify
-                    lg, c2 = fn(self.weights_for(fmt), bv, cache, cache_len)
-                    if fi is not None:
-                        lg = fi.maybe_poison_logits(tick, fmt, lg)
-                    return lg, c2
+                    def vattempt(fmt, bv=batch_v):
+                        if fi is not None:
+                            fi.maybe_raise_step(tick)
+                        fn = self._packed_verify if self._serves_packed(fmt) \
+                            else self._dense_verify
+                        lg, c2 = fn(self.weights_for(fmt), bv, cache,
+                                    cache_len)
+                        if fi is not None:
+                            lg = fi.maybe_poison_logits(tick, fmt, lg)
+                        return lg, c2
 
-                logits3, cache, new_pinned, dead, vexecs = \
-                    self._guarded_decode(vattempt, pinned, consumed, tick,
-                                         finite_fn=self._finite_rows_mq)
-                if new_pinned != pinned:
-                    pinned = repin(new_pinned)
-                tick_execs += draft_execs + vexecs
-                tick_rows += b * (draft_execs + vexecs)
+                    logits3, cache, new_pinned, dead, vexecs = \
+                        self._guarded_decode(vattempt, pinned, consumed, tick,
+                                             "verify",
+                                             finite_fn=self._finite_rows_mq)
+                    with self._phase("fetch", what="tokens"):
+                        # every committed token is the VERIFY format's own
+                        # argmax (accepted drafts equal it by definition),
+                        # which is the whole bit-identity guarantee
+                        anchor_toks = np.asarray(
+                            jnp.argmax(logits3, -1))  # (b, C)
+                    with self._phase("retire"):
+                        if new_pinned != pinned:
+                            pinned = repin(new_pinned)
+                        tick_execs += draft_execs + vexecs
+                        tick_rows += b * (draft_execs + vexecs)
 
-                # ---- accept/commit: every committed token is the VERIFY
-                # format's own argmax (accepted drafts equal it by
-                # definition), which is the whole bit-identity guarantee.
-                anchor_toks = np.asarray(jnp.argmax(logits3, -1))  # (b, C)
-                budgets = np.zeros(b, np.int64)
-                for i in consumed:
-                    if i not in dead:
-                        budgets[i] = buds[i]
-                commit = spec_accept_counts(drafts, anchor_toks, budgets)
-                cache_len = cache_len + jnp.asarray(commit, jnp.int32) \
-                    * jnp.asarray(mask)
-                nxt_np = np.array([anchor_toks[i, max(int(commit[i]) - 1, 0)]
-                                   for i in range(b)], np.int64)
-                tokens = jnp.asarray(nxt_np, jnp.int32)[:, None]
-                self._ticks += 1
-                self._spec_ticks += 1
-                for i in consumed:
-                    if i not in dead:
-                        acc = int(commit[i]) - 1
-                        self._spec_accepted += acc
-                        self._spec_rejected += k_eff - acc
+                        # ---- accept/commit
+                        budgets = np.zeros(b, np.int64)
+                        for i in consumed:
+                            if i not in dead:
+                                budgets[i] = buds[i]
+                        commit = spec_accept_counts(drafts, anchor_toks,
+                                                    budgets)
+                        cache_len = cache_len \
+                            + jnp.asarray(commit, jnp.int32) \
+                            * jnp.asarray(mask)
+                        nxt_np = np.array(
+                            [anchor_toks[i, max(int(commit[i]) - 1, 0)]
+                             for i in range(b)], np.int64)
+                        tokens = jnp.asarray(nxt_np, jnp.int32)[:, None]
+                        self._ticks += 1
+                        self._spec_ticks += 1
+                        for i in consumed:
+                            if i not in dead:
+                                acc = int(commit[i]) - 1
+                                self._spec_accepted += acc
+                                self._spec_rejected += k_eff - acc
 
-                # Attention-read accounting: k_eff single-query walks at a
-                # growing cursor plus vexecs multi-query walks per live
-                # slot (mirrors the plain tick's arithmetic below).
-                window = self.api.cfg.sliding_window
-                for i in range(b):
-                    if not (paged and self.attn_impl == "paged_kernel"):
-                        self._attn_tokens_read += \
-                            self._attn_read_span * (draft_execs + vexecs)
-                    elif active[i] is not None:
-                        for j in range(draft_execs):
-                            self._attn_tokens_read += pages_read(
-                                slot_len[i] + 1 + j, ps, window) * ps
-                        self._attn_tokens_read += vexecs * pages_read_mq(
-                            slot_len[i], cdim, ps, window) * ps
-                    elif filling is not None and i == fill_slot:
-                        self._attn_tokens_read += \
-                            (draft_execs + vexecs) * pages_read(
-                                fill_cursor + 1, ps, window) * ps
+                        # Attention-read accounting: k_eff single-query
+                        # walks at a growing cursor plus vexecs multi-query
+                        # walks per live slot (mirrors the plain tick's
+                        # arithmetic below).
+                        window = self.api.cfg.sliding_window
+                        kernel = paged and self.attn_impl == "paged_kernel"
+                        for i in range(b):
+                            if not kernel:
+                                self._attn_tokens_read += \
+                                    self._attn_read_span \
+                                    * (draft_execs + vexecs)
+                            elif active[i] is not None:
+                                for j in range(draft_execs):
+                                    self._attn_tokens_read += pages_read(
+                                        slot_len[i] + 1 + j, ps, window) * ps
+                                self._attn_tokens_read += \
+                                    vexecs * pages_read_mq(
+                                        slot_len[i], cdim, ps, window) * ps
+                            elif filling is not None and i == fill_slot:
+                                self._attn_tokens_read += \
+                                    (draft_execs + vexecs) * pages_read(
+                                        fill_cursor + 1, ps, window) * ps
+                            else:
+                                self._attn_tokens_read += \
+                                    (draft_execs + vexecs) * ps
+
+                        # Dead rows (non-finite verify logits at the anchor
+                        # rung): retire before the drain, exactly like a
+                        # plain tick — no draft of theirs was committed
+                        # (budget forced to 0).
+                        for i in dead:
+                            r_dead = active[i]
+                            if r_dead is None:
+                                continue
+                            active[i] = None
+                            release_slot(i)
+                            self._finish(
+                                r_dead, RequestStatus.FAILED_NUMERIC,
+                                f"non-finite logits in this request's row "
+                                f"at the anchor rung ({pinned}), verify tick "
+                                f"{tick}")
+
+                        # ---- drain + rewind: commit[i] tokens enter the
+                        # stream; pages past the new frontier go straight
+                        # back to the free list (the KV "rollback" is just
+                        # these two lines — no data moves, stale positions
+                        # are masked by cache_len).
+                        for i, r in enumerate(active):
+                            if r is None:
+                                continue
+                            n_c = int(commit[i])
+                            slot_len[i] += n_c
+                            r.out_tokens.extend(int(t)
+                                                for t in anchor_toks[i, :n_c])
+                            self._tokens_out += n_c
+                            if paged:
+                                self._rollback_slot_pages(free_pages, bt, i,
+                                                          slot_len[i])
+                            if len(r.out_tokens) >= r.max_new or \
+                                    slot_len[i] >= self.prompt_capacity:
+                                self._finish(r, RequestStatus.COMPLETED)
+                                active[i] = None
+                                release_slot(i)
+                        if paged:
+                            cache["block_table"] = jnp.asarray(bt)
+                    self._record_tick(tick_pf_tokens, tick_pf_chunks, 1,
+                                      time.perf_counter() - t_tick,
+                                      execs=tick_execs, rows=tick_rows,
+                                      decode_rows=int(mask.sum()),
+                                      draft_execs=draft_execs,
+                                      verify_execs=vexecs)
+                    if all(a is None for a in active) and filling is None:
+                        pinned = None
+                    continue
+
+                if chunk_tok is not None:
+                    # ---- mixed tick: the staged chunk rides the decode
+                    # batch as ONE executable. Decode rows keep their
+                    # 1-token budget in column 0; the fill row carries the
+                    # whole chunk at its cursor. Free rows stay masked
+                    # exactly as under serve_step (q_len=1, cursor frozen,
+                    # scratch-page writes).
+                    start, take, padded, final = chunk_tok
+                    with self._phase("stage"):
+                        tok2d = jnp.zeros((b, padded), jnp.int32) \
+                            .at[:, 0].set(tokens[:, 0]) \
+                            .at[fill_slot].set(jnp.asarray(ctoks))
+                        q_len_np = np.ones(b, np.int32)
+                        q_len_np[fill_slot] = take
+                        batch_mx = {"tokens": tok2d,
+                                    "q_len": jnp.asarray(q_len_np)}
+
+                    def attempt(fmt, bm=batch_mx):
+                        if fi is not None:
+                            fi.maybe_raise_step(tick)
+                        fn = self._packed_mixed if self._serves_packed(fmt) \
+                            else self._dense_mixed
+                        lg, c2 = fn(self.weights_for(fmt), bm, cache,
+                                    cache_len)
+                        if fi is not None:
+                            lg = fi.maybe_poison_logits(tick, fmt, lg)
+                        return lg, c2
+                else:
+                    def attempt(fmt):
+                        if fi is not None:
+                            fi.maybe_raise_step(tick)
+                        fn = self._packed_step if self._serves_packed(fmt) \
+                            else self._dense_step
+                        lg, c2 = fn(self.weights_for(fmt),
+                                    {"tokens": tokens}, cache, cache_len)
+                        if fi is not None:
+                            lg = fi.maybe_poison_logits(tick, fmt, lg)
+                        return lg, c2
+
+                # Escalate-and-replay runs HERE, against pre-tick state; the
+                # commits below (cache_len advance, batched draw, token
+                # drain) happen exactly once, after the guard settles.
+                logits, cache, new_pinned, dead, execs = \
+                    self._guarded_decode(
+                        attempt, pinned, consumed, tick,
+                        "mixed" if chunk_tok is not None else "decode")
+                with self._phase("retire"):
+                    if new_pinned != pinned:
+                        pinned = repin(new_pinned)
+                    tick_execs += execs
+                    tick_rows += b * execs
+                    if chunk_tok is not None:
+                        adv = mask.copy()
+                        adv[fill_slot] = take
+                        cache_len = cache_len + jnp.asarray(adv)
+                        tick_pf_tokens += padded
+                        tick_pf_chunks += 1
                     else:
-                        self._attn_tokens_read += \
-                            (draft_execs + vexecs) * ps
+                        cache_len = cache_len + jnp.asarray(mask)
+                    # The batched draw advances EVERY slot key once per
+                    # decode-carrying tick — the fill row's draw is
+                    # discarded, and if its chunk completed this tick,
+                    # complete_admission reseeds the key from scratch below,
+                    # so the stream matches sequential admission bit for
+                    # bit.
+                    nxt = self._sample(logits, greedy)
+                    tokens = nxt[:, None].astype(jnp.int32)
+                    self._ticks += 1
+                    attn_before = self._attn_tokens_read
 
-                # Dead rows (non-finite verify logits at the anchor rung):
-                # retire before the drain, exactly like a plain tick — no
-                # draft of theirs was committed (budget forced to 0).
-                for i in dead:
-                    r_dead = active[i]
-                    if r_dead is None:
-                        continue
-                    active[i] = None
-                    release_slot(i)
-                    self._finish(
-                        r_dead, RequestStatus.FAILED_NUMERIC,
-                        f"non-finite logits in this request's row at the "
-                        f"anchor rung ({pinned}), verify tick {tick}")
+                    # Attention-read accounting for the tick that just ran.
+                    # Every batch row is processed (free/mid-prefill slots
+                    # are masked, not removed): gather (and the dense
+                    # layout) materializes the full logical span for ALL
+                    # rows; the kernel walks pages_read(...) distinct pages
+                    # (kernels/paged_attention.py — the one home of that
+                    # clamp arithmetic) for rows with mapped pages —
+                    # decoding slots at slot_len+1, the mid-prefill slot at
+                    # its cursor+1 — and a single clamped-revisit scratch
+                    # page for zeroed rows (every walk step maps to page 0,
+                    # so Pallas elides the repeats).
+                    window = self.api.cfg.sliding_window
+                    for i in range(b):
+                        if not (paged and self.attn_impl == "paged_kernel"):
+                            self._attn_tokens_read += self._attn_read_span
+                        elif active[i] is not None:
+                            self._attn_tokens_read += \
+                                pages_read(slot_len[i] + 1, ps, window) * ps
+                        elif chunk_tok is not None and i == fill_slot:
+                            # Mixed tick: the fill row's ragged query span
+                            # walks its own clamped page range
+                            # (pages_read_mq mirrors the MQ kernel's
+                            # arithmetic the way pages_read mirrors the
+                            # single-query kernel's).
+                            self._attn_tokens_read += \
+                                pages_read_mq(start, take, ps, window) * ps
+                        elif filling is not None and i == fill_slot:
+                            self._attn_tokens_read += \
+                                pages_read(fill_cursor + 1, ps, window) * ps
+                        else:
+                            self._attn_tokens_read += ps
 
-                # ---- drain + rewind: commit[i] tokens enter the stream;
-                # pages past the new frontier go straight back to the free
-                # list (the KV "rollback" is just these two lines — no data
-                # moves, stale positions are masked by cache_len).
-                for i, r in enumerate(active):
-                    if r is None:
-                        continue
-                    n_c = int(commit[i])
-                    slot_len[i] += n_c
-                    r.out_tokens.extend(int(t)
-                                        for t in anchor_toks[i, :n_c])
-                    self._tokens_out += n_c
-                    if paged:
-                        self._rollback_slot_pages(free_pages, bt, i,
-                                                  slot_len[i])
-                    if len(r.out_tokens) >= r.max_new or \
-                            slot_len[i] >= self.prompt_capacity:
-                        self._finish(r, RequestStatus.COMPLETED)
+                    # ---- dead rows (non-finite logits at the anchor rung):
+                    # the fault is confined to these requests — retire them
+                    # BEFORE the drain so no poisoned token ever enters a
+                    # stream; every other slot's draw this tick is
+                    # untouched.
+                    for i in dead:
+                        if filling is not None and i == fill_slot:
+                            release_slot(i)
+                            self._finish(
+                                filling, RequestStatus.FAILED_NUMERIC,
+                                f"non-finite final-chunk logits in this "
+                                f"request's row at the anchor rung "
+                                f"({pinned}), tick {tick}")
+                            filling = None
+                            continue
+                        r_dead = active[i]
+                        if r_dead is None:
+                            continue
                         active[i] = None
                         release_slot(i)
-                if paged:
-                    cache["block_table"] = jnp.asarray(bt)
+                        self._finish(
+                            r_dead, RequestStatus.FAILED_NUMERIC,
+                            f"non-finite logits in this request's row at "
+                            f"the anchor rung ({pinned}), tick {tick}")
+
+                # ---- retire: ONE host transfer per tick drains every slot
+                with self._phase("fetch", what="tokens"):
+                    drained = np.asarray(nxt)
+                with self._phase("retire"):
+                    for i, r in enumerate(active):
+                        if r is None:
+                            continue
+                        slot_len[i] += 1
+                        r.out_tokens.append(int(drained[i]))
+                        self._tokens_out += 1
+                        if len(r.out_tokens) >= r.max_new or \
+                                slot_len[i] >= self.prompt_capacity:
+                            self._finish(r, RequestStatus.COMPLETED)
+                            active[i] = None   # slot re-admissible next tick
+                            release_slot(i)    # pages recycle on next admit
+                    # ---- mixed-tick chunk epilogue: advance the cursor, and
+                    # if the chunk reached the prompt end, complete
+                    # admission from the fill row's logits — AFTER the
+                    # batched draw above, so the reseed overwrites the
+                    # discarded draw's key advance. (A dead fill row already
+                    # retired FAILED_NUMERIC above.)
+                    admitted = chunk_tok is not None and final \
+                        and filling is not None
+                    if chunk_tok is not None:
+                        fill_cursor = start + take
+                    if admitted:
+                        slot_len[fill_slot] = plen
+                        fill_logits = logits[fill_slot]
+                if admitted:
+                    complete_admission(fill_slot, filling, fill_logits)
+                    filling = None
+                # ---- cost-model calibration: only CLEAN pure-decode ticks
+                # (no prefill work, exactly one executable — no replays) are
+                # attributable to the pinned format's per-tick cost; the
+                # measured attention read refreshes the per-row byte term.
+                with self._phase("retire"):
+                    cost = self.policy.cost
+                    rows_d = int(mask.sum())
+                    if cost is not None and rows_d and tick_pf_chunks == 0 \
+                            and tick_execs == 1:
+                        seen = self._fmt_decode_ticks.get(pinned, 0)
+                        self._fmt_decode_ticks[pinned] = seen + 1
+                        if seen:   # a format's first clean tick pays jit
+                            #        compile — warmup, not cost; never fold
+                            #        it into the model
+                            cost.observe(
+                                pinned, rows_d, time.perf_counter() - t_tick,
+                                attn_bytes_per_row=(self._attn_tokens_read
+                                                    - attn_before)
+                                * self._attn_token_bytes / rows_d)
                 self._record_tick(tick_pf_tokens, tick_pf_chunks, 1,
                                   time.perf_counter() - t_tick,
                                   execs=tick_execs, rows=tick_rows,
-                                  decode_rows=int(mask.sum()),
-                                  draft_execs=draft_execs,
-                                  verify_execs=vexecs)
+                                  decode_rows=rows_d)
                 if all(a is None for a in active) and filling is None:
                     pinned = None
-                continue
-
-            if chunk_tok is not None:
-                # ---- mixed tick: the staged chunk rides the decode batch as
-                # ONE executable. Decode rows keep their 1-token budget in
-                # column 0; the fill row carries the whole chunk at its
-                # cursor. Free rows stay masked exactly as under serve_step
-                # (q_len=1, cursor frozen, scratch-page writes).
-                start, take, padded, final = chunk_tok
-                tok2d = jnp.zeros((b, padded), jnp.int32) \
-                    .at[:, 0].set(tokens[:, 0]) \
-                    .at[fill_slot].set(jnp.asarray(ctoks))
-                q_len_np = np.ones(b, np.int32)
-                q_len_np[fill_slot] = take
-                batch_mx = {"tokens": tok2d, "q_len": jnp.asarray(q_len_np)}
-
-                def attempt(fmt, bm=batch_mx):
-                    if fi is not None:
-                        fi.maybe_raise_step(tick)
-                    fn = self._packed_mixed if self._serves_packed(fmt) \
-                        else self._dense_mixed
-                    lg, c2 = fn(self.weights_for(fmt), bm, cache, cache_len)
-                    if fi is not None:
-                        lg = fi.maybe_poison_logits(tick, fmt, lg)
-                    return lg, c2
-            else:
-                def attempt(fmt):
-                    if fi is not None:
-                        fi.maybe_raise_step(tick)
-                    fn = self._packed_step if self._serves_packed(fmt) \
-                        else self._dense_step
-                    lg, c2 = fn(self.weights_for(fmt), {"tokens": tokens},
-                                cache, cache_len)
-                    if fi is not None:
-                        lg = fi.maybe_poison_logits(tick, fmt, lg)
-                    return lg, c2
-
-            # Escalate-and-replay runs HERE, against pre-tick state; the
-            # commits below (cache_len advance, batched draw, token drain)
-            # happen exactly once, after the guard settles.
-            logits, cache, new_pinned, dead, execs = self._guarded_decode(
-                attempt, pinned, consumed, tick)
-            if new_pinned != pinned:
-                pinned = repin(new_pinned)
-            tick_execs += execs
-            tick_rows += b * execs
-            if chunk_tok is not None:
-                adv = mask.copy()
-                adv[fill_slot] = take
-                cache_len = cache_len + jnp.asarray(adv)
-                tick_pf_tokens += padded
-                tick_pf_chunks += 1
-            else:
-                cache_len = cache_len + jnp.asarray(mask)
-            # The batched draw advances EVERY slot key once per decode-
-            # carrying tick — the fill row's draw is discarded, and if its
-            # chunk completed this tick, complete_admission reseeds the key
-            # from scratch below, so the stream matches sequential admission
-            # bit for bit.
-            nxt = self._sample(logits, greedy)
-            tokens = nxt[:, None].astype(jnp.int32)
-            self._ticks += 1
-            attn_before = self._attn_tokens_read
-
-            # Attention-read accounting for the tick that just ran. Every
-            # batch row is processed (free/mid-prefill slots are masked, not
-            # removed): gather (and the dense layout) materializes the full
-            # logical span for ALL rows; the kernel walks pages_read(...)
-            # distinct pages (kernels/paged_attention.py — the one home of
-            # that clamp arithmetic) for rows with mapped pages — decoding
-            # slots at slot_len+1, the mid-prefill slot at its cursor+1 —
-            # and a single clamped-revisit scratch page for zeroed rows
-            # (every walk step maps to page 0, so Pallas elides the repeats).
-            window = self.api.cfg.sliding_window
-            for i in range(b):
-                if not (paged and self.attn_impl == "paged_kernel"):
-                    self._attn_tokens_read += self._attn_read_span
-                elif active[i] is not None:
-                    self._attn_tokens_read += \
-                        pages_read(slot_len[i] + 1, ps, window) * ps
-                elif chunk_tok is not None and i == fill_slot:
-                    # Mixed tick: the fill row's ragged query span walks its
-                    # own clamped page range (pages_read_mq mirrors the MQ
-                    # kernel's arithmetic the way pages_read mirrors the
-                    # single-query kernel's).
-                    self._attn_tokens_read += \
-                        pages_read_mq(start, take, ps, window) * ps
-                elif filling is not None and i == fill_slot:
-                    self._attn_tokens_read += \
-                        pages_read(fill_cursor + 1, ps, window) * ps
-                else:
-                    self._attn_tokens_read += ps
-
-            # ---- dead rows (non-finite logits at the anchor rung): the
-            # fault is confined to these requests — retire them BEFORE the
-            # drain so no poisoned token ever enters a stream; every other
-            # slot's draw this tick is untouched.
-            for i in dead:
-                if filling is not None and i == fill_slot:
-                    release_slot(i)
-                    self._finish(
-                        filling, RequestStatus.FAILED_NUMERIC,
-                        f"non-finite final-chunk logits in this request's "
-                        f"row at the anchor rung ({pinned}), tick {tick}")
-                    filling = None
-                    continue
-                r_dead = active[i]
-                if r_dead is None:
-                    continue
-                active[i] = None
-                release_slot(i)
-                self._finish(
-                    r_dead, RequestStatus.FAILED_NUMERIC,
-                    f"non-finite logits in this request's row at the "
-                    f"anchor rung ({pinned}), tick {tick}")
-
-            # ---- retire: ONE host transfer per tick drains every slot
-            drained = np.asarray(nxt)
-            for i, r in enumerate(active):
-                if r is None:
-                    continue
-                slot_len[i] += 1
-                r.out_tokens.append(int(drained[i]))
-                self._tokens_out += 1
-                if len(r.out_tokens) >= r.max_new or \
-                        slot_len[i] >= self.prompt_capacity:
-                    self._finish(r, RequestStatus.COMPLETED)
-                    active[i] = None       # slot re-admissible next tick
-                    release_slot(i)        # pages recycle on the next admit
-            if chunk_tok is not None:
-                # ---- mixed-tick chunk epilogue: advance the cursor, and if
-                # the chunk reached the prompt end, complete admission from
-                # the fill row's logits — AFTER the batched draw above, so
-                # the reseed overwrites the discarded draw's key advance.
-                # (A dead fill row already retired FAILED_NUMERIC above.)
-                fill_cursor = start + take
-                if final and filling is not None:
-                    slot_len[fill_slot] = plen
-                    complete_admission(fill_slot, filling, logits[fill_slot])
-                    filling = None
-            # ---- cost-model calibration: only CLEAN pure-decode ticks
-            # (no prefill work, exactly one executable — no replays) are
-            # attributable to the pinned format's per-tick cost; the
-            # measured attention read refreshes the per-row byte term.
-            cost = self.policy.cost
-            rows_d = int(mask.sum())
-            if cost is not None and rows_d and tick_pf_chunks == 0 \
-                    and tick_execs == 1:
-                seen = self._fmt_decode_ticks.get(pinned, 0)
-                self._fmt_decode_ticks[pinned] = seen + 1
-                if seen:   # a format's first clean tick pays jit compile —
-                    #        warmup, not cost; never fold it into the model
-                    cost.observe(
-                        pinned, rows_d, time.perf_counter() - t_tick,
-                        attn_bytes_per_row=(self._attn_tokens_read
-                                            - attn_before)
-                        * self._attn_token_bytes / rows_d)
-            self._record_tick(tick_pf_tokens, tick_pf_chunks, 1,
-                              time.perf_counter() - t_tick,
-                              execs=tick_execs, rows=tick_rows,
-                              decode_rows=rows_d)
-            if all(a is None for a in active) and filling is None:
-                pinned = None
         return requests
 
     @staticmethod
@@ -2008,14 +2156,77 @@ class ElasticEngine:
         invariants stay assertable under speculation: a non-spec tick's
         plain executables are exactly
         ``execs - draft_execs - verify_execs``.
+
+        The open tick's record (``_phase``) adds: ``kind``, the kind of
+        its last step dispatch (``idle`` when none ran); ``phase_s``, host
+        seconds per phase, which sum to at most ``wall_s`` since this runs
+        after the tick's last phase closes; and, on the engine clock
+        (seconds since ``generate`` started, as ``Request.arrival_s``),
+        ``dispatched_s`` when its first step dispatch began, ``fetched_s``
+        when its last device-to-host fetch ended and ``step_s``, the last
+        dispatch's start to the end of the first fetch after it — the step
+        latency the host observed. Each is None when the tick dispatched
+        (or fetched) nothing; a chunk that runs alone with no logits to
+        read is never fetched.
         """
+        rec = self._tick_rec
         self.tick_trace.append({"prefill_tokens": prefill_tokens,
                                 "prefill_chunks": prefill_chunks,
                                 "decode": decode, "wall_s": wall_s,
                                 "execs": execs, "rows": rows,
                                 "decode_rows": decode_rows,
                                 "draft_execs": draft_execs,
-                                "verify_execs": verify_execs})
+                                "verify_execs": verify_execs,
+                                "kind": rec["kind"],
+                                "phase_s": dict(rec["phase_s"]),
+                                "dispatched_s": rec["dispatched_s"],
+                                "fetched_s": rec["fetched_s"],
+                                "step_s": rec["step_s"]})
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **attrs):
+        """Time one stretch of host work as the span ``engine.<name>``.
+
+        The span is a ``jax.profiler.TraceAnnotation``: while a profiler
+        runs, it lands on the host plane on the clock of the device's ops,
+        so an operator's trace names each idle gap of the device by the
+        host phase over it; with no profiler it costs well under a
+        microsecond. ``tick`` opens a tick's record (``_record_tick``).
+        The phases under it are siblings and add their seconds to
+        ``phase_s``; a ``dispatch`` (attribute ``kind``) and a ``fetch``
+        also stamp the tick. A phase opened inside another (a conversion
+        under a dispatch) is a span only: its time is its parent's.
+        """
+        t = time.perf_counter()
+        if name == "tick":
+            self._tick_rec = {"kind": "idle", "phase_s": {},
+                              "dispatched_s": None, "fetched_s": None,
+                              "step_s": None}
+            self._step_t = None
+        rec, nested = self._tick_rec, self._in_phase
+        self._in_phase = name != "tick"
+        try:
+            with jax.profiler.TraceAnnotation(f"engine.{name}",
+                                              **attrs) as span:
+                yield span
+        finally:
+            end = time.perf_counter()
+            self._in_phase = nested
+            if name == "tick":
+                self._tick_rec = None
+            elif rec is not None and not nested:
+                ph = rec["phase_s"]
+                ph[name] = ph.get(name, 0.0) + end - t
+                if name == "dispatch":
+                    rec["kind"] = attrs["kind"]
+                    if rec["dispatched_s"] is None:
+                        rec["dispatched_s"] = t - self._t0
+                    self._step_t = t
+                elif name == "fetch":
+                    rec["fetched_s"] = end - self._t0
+                    if self._step_t is not None:
+                        rec["step_s"] = end - self._step_t
+                        self._step_t = None
 
     def _free_slot_pages(self, free_pages: List[int], bt: np.ndarray,
                          slot: int) -> None:
@@ -2162,6 +2373,7 @@ class ElasticEngine:
                           "arrival_tick": int(r.arrival_tick),
                           "arrival_s": r.arrival_s,
                           "admitted_tick": r.admitted_tick,
+                          "admitted_s": r.admitted_s,
                           "temperature": r.temperature,
                           "top_p": r.top_p}
                          for r in requests],
@@ -2260,6 +2472,7 @@ class ElasticEngine:
             r.arrival_tick = int(rd.get("arrival_tick", 0))
             r.arrival_s = rd.get("arrival_s")
             r.admitted_tick = rd.get("admitted_tick")
+            r.admitted_s = rd.get("admitted_s")
             r.temperature = rd.get("temperature")
             r.top_p = rd.get("top_p")
             by_rid[r.rid] = r
@@ -2331,13 +2544,12 @@ class ElasticEngine:
             "fmt_swaps": self._fmt_swaps,
             "ticks": self._ticks,
             "tokens_out": self._tokens_out,
-            "current": self.current_fmt,
             "fused": self.fused,
-            "prefill_traces": self._prefill_traces,
+            "prefill_traces": sum(self._traces.values()),
+            "traces": dict(self._traces),
             "prefill_chunk": self.prefill_chunk,
             "admission_requeues": self._admission_requeues,
             "kv_layout": self.kv_layout,
-            "kv_cache_bytes": self._kv_cache_bytes,
             "kv_bytes_per_slot": self._kv_cache_bytes // self.slots,
             "kv_page_size": self.kv_page_size,
             "kv_total_pages": self._kv_total_pages,
@@ -2354,7 +2566,6 @@ class ElasticEngine:
                 self._spec_accepted
                 / (self._spec_accepted + self._spec_rejected)
                 if self._spec_accepted + self._spec_rejected else None),
-            "logit_guard": self.logit_guard,
             "faults_detected": self._faults_detected,
             "fmt_escalations": self._fmt_escalations,
             "escalation_events": list(self._escalation_events),

@@ -307,8 +307,10 @@ def make_packed_fn(api, fn, block_size: int = 32):
     Densification runs *inside* the (to-be-jitted) call, so the resident /
     HBM-streamed weights are the packed bytes and the dequant fuses into the
     consuming matmuls. This is the XLA fallback contract; the fused contract
-    (``fused=True`` below) skips densification entirely.
+    (``fused=True`` below) skips densification entirely. The wrapper keeps
+    ``fn``'s name, so the step compiles as ``jit_<name>``.
     """
+    @functools.wraps(fn)
     def wrapped(packed_params, *rest):
         params = densify_params(packed_params, block_size,
                                 api.cfg.compute_dtype)
