@@ -36,9 +36,14 @@ prefill+decode tick: each row carries a ragged span of ``q_len`` queries at
 cursor ``q_offset`` (decode rows 1, the mid-prefill row a whole chunk), the
 causal mask is per query lane (``pos <= q_offset + i``), and the same
 clamped block-table walk bounds DMA to the pages each q block's live lanes
-can see (``pages_read_mq``). It subsumes the single-query kernel
-(``q_len == 1`` rows cost and compute identically) and retires the
-gather-based chunked-prefill read path on TPU.
+can see (``pages_read_mq``). ``q_len == 1`` rows DMA exactly the pages the
+single-query kernel reads; they do not compute what it computes, since a
+q block holds ``tq * G`` query rows per kv head and a decode row has one
+live lane. The narrow fold bounds that: a block with ``NARROW_LANES`` or
+fewer live lanes folds only its first ``NARROW_LANES * G`` rows, every
+other block folds whole (``mq_rows_folded`` mirrors the rule on the
+host). The kernel retires the gather-based chunked-prefill read path on
+TPU.
 
 Dispatch (mirroring kernels/dispatch.py): ``paged_decode_attention`` is the
 serving entry point. Mode "pallas" runs this kernel — Mosaic on TPU,
@@ -134,6 +139,32 @@ def pages_read_mq(q_offset: int, q_len: int, page_size: int,
     return last - first + 1
 
 
+# Query lanes a multi-query block folds when it has this many live lanes or
+# fewer (the narrow fold; ``_paged_attn_mq_kernel``). NARROW_LANES * G rows
+# is a multiple of 8 for every G, so the narrow extent stays sublane-aligned.
+NARROW_LANES = 8
+
+
+def mq_rows_folded(q_len: int, c: int, g: int,
+                   tq: Optional[int] = None) -> int:
+    """Query rows one row's multi-query call folds per kv head — the host
+    mirror of the MQ kernel's narrow-fold rule, as ``pages_read_mq`` is of
+    its walk. Per q block of ``tq`` lanes (default ``c``, one block): none
+    for a block with no live lane, ``min(NARROW_LANES, tq) * g`` for a
+    block with that many live lanes or fewer, ``tq * g`` otherwise. The
+    padded count it compares with is ``c * g``."""
+    tq = c if tq is None else tq
+    narrow = min(NARROW_LANES, tq)
+    rows = 0
+    for qi in range(c // tq):
+        lanes = q_len - qi * tq
+        if lanes > narrow:
+            rows += tq * g
+        elif lanes > 0:
+            rows += narrow * g
+    return rows
+
+
 # ---------------------------------------------------------------------------
 # The kernels
 # ---------------------------------------------------------------------------
@@ -150,10 +181,14 @@ def _init_scratch(m_ref, l_ref, acc_ref):
 
 
 def _fold_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask, vvalid, *,
-               hkv: int, d: int):
+               hkv: int, d: int, rows: Optional[int] = None):
     """Fold one page into every kv head's flash partial softmax.
 
-    ``mask`` (rows, ps) says which (query row, key) pairs are live,
+    Only the first ``rows`` query rows of the block fold (all when None; a
+    static extent, so every slice starts at 0 and needs no dynamic sublane
+    offset); rows past it keep their initial scratch (``l == 0``) and
+    finalize to exact zeros. ``mask`` (rows, ps) says which (query row,
+    key) pairs are live,
     ``vvalid`` (ps, 1) which V rows may enter the PV product. Masking is
     total: scores are -inf'd BEFORE the max (dead positions may hold NaN —
     poisoned / recycled pages — and NaN propagates through jnp.maximum), p
@@ -163,22 +198,23 @@ def _fold_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask, vvalid, *,
     valid position yet would otherwise NaN on exp(-inf - -inf).
     """
     scale = 1.0 / (d ** 0.5)
+    r = slice(None, rows)
     for h in range(hkv):
         cols = slice(h * d, (h + 1) * d)
-        q = q_ref[0, h].astype(jnp.float32)                  # (rows, D)
+        q = q_ref[0, h, r].astype(jnp.float32)               # (rows, D)
         k = k_ref[0, :, cols].astype(jnp.float32)            # (ps, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(mask, s, -jnp.inf)
-        m_prev = m_ref[h]                                    # (rows, 1)
+        m_prev = m_ref[h, r]                                 # (rows, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(jnp.where(m_new > -jnp.inf, m_prev - m_new, 0.0))
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         v = jnp.where(vvalid, v_ref[0, :, cols].astype(jnp.float32), 0.0)
-        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+        l_ref[h, r] = l_ref[h, r] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h, r] = acc_ref[h, r] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
-        m_ref[h] = m_new
+        m_ref[h, r] = m_new
 
 
 def _finalize(o_ref, l_ref, acc_ref, *, hkv: int):
@@ -315,11 +351,16 @@ def _paged_attn_mq_kernel(bt_ref, qo_ref, ql_ref, q_ref, k_ref, v_ref, o_ref,
     Scratch persists across the j-minor KV walk of one (slot, q block).
     Unlike the single-query kernel, a page the walk visits can be live for
     some lanes and dead for others, so the running max is per row.
+
+    The narrow fold: a block whose live lanes number ``NARROW_LANES`` or
+    fewer (decode rows, short verify spans, a short final chunk) folds
+    only its first ``NARROW_LANES * g`` rows; the rows past them are dead
+    lanes, which keep ``l == 0`` and finalize to exact zeros either way.
+    ``mq_rows_folded`` is the host mirror of this rule.
     """
     b = pl.program_id(0)
     qi = pl.program_id(1)
     j = pl.program_id(2)
-    rows = tq * g
 
     @pl.when(j == 0)
     def _init():
@@ -329,24 +370,34 @@ def _paged_attn_mq_kernel(bt_ref, qo_ref, ql_ref, q_ref, k_ref, v_ref, o_ref,
     q_len = ql_ref[b]
     live = q_offset + q_len                 # KV frontier after this tick
     base = q_offset + qi * tq               # position of the block's lane 0
-    r = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
-    pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, page_size), 1)
-    # (rows, ps), with lane i = r // g compared through r alone (no vector
-    # integer division): causal self-inclusive (pos <= base + i), clipped
-    # at the frontier, dead for pad lanes (i < q_len - qi*tq), windowed
-    # (base + i - pos < W).
-    mask = (r >= (pos - base) * g) & (pos < live)
-    mask &= r < (q_len - qi * tq) * g
-    if window is not None:
-        mask &= r < (pos + window - base) * g
+    lanes = q_len - qi * tq                 # live lanes in this block
     vvalid = j * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (page_size, 1), 0) < live
 
-    @pl.when(jnp.any(mask))
-    def _accumulate():
-        _fold_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask, vvalid,
-                   hkv=hkv, d=d)
+    def fold(rows):
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
+        pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 1)
+        # (rows, ps), with lane i = r // g compared through r alone (no
+        # vector integer division): causal self-inclusive (pos <= base +
+        # i), clipped at the frontier, dead for pad lanes (i < lanes),
+        # windowed (base + i - pos < W).
+        mask = (r >= (pos - base) * g) & (pos < live)
+        mask &= r < lanes * g
+        if window is not None:
+            mask &= r < (pos + window - base) * g
+
+        @pl.when(jnp.any(mask))
+        def _accumulate():
+            _fold_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, mask,
+                       vvalid, hkv=hkv, d=d, rows=rows)
+
+    narrow = min(NARROW_LANES, tq)
+    if narrow == tq:
+        fold(tq * g)
+    else:
+        pl.when(lanes <= narrow)(lambda: fold(narrow * g))
+        pl.when(lanes > narrow)(lambda: fold(tq * g))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _done():

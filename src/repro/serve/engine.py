@@ -92,7 +92,8 @@ from repro.checkpoint import io as ckpt_io
 from repro.core.anchor import AnchorModel, convert, materialize
 from repro.core.formats import get_format
 from repro.core.mx import MXTensor
-from repro.kernels.paged_attention import pages_read, pages_read_mq
+from repro.kernels.paged_attention import (mq_rows_folded, pages_read,
+                                           pages_read_mq)
 from repro.models.common import spec_accept_counts
 from repro.models.transformer import ModelApi, make_model
 from repro.runtime.fault import InjectedFault
@@ -435,6 +436,8 @@ class ElasticEngine:
         self.attn_impl = attn_impl
         self._attn_tokens_read = 0   # KV tokens decode attention read (host
         #                              mirror; see stats()["attn_tokens_read"])
+        self._mq_rows_folded = 0     # MQ kernel query rows folded / held
+        self._mq_rows_padded = 0     # (see _count_mq_rows)
         cfg = api.cfg
         self._attn_layers = 0 if cfg.family == "ssm" else sum(
             cfg.is_attn_layer(j) for j in range(cfg.scan_group)) \
@@ -1891,6 +1894,8 @@ class ElasticEngine:
                             else:
                                 self._attn_tokens_read += \
                                     (draft_execs + vexecs) * ps
+                        if kernel:
+                            self._count_mq_rows(q_np, cdim, vexecs)
 
                         # Dead rows (non-finite verify logits at the anchor
                         # rung): retire before the drain, exactly like a
@@ -2023,8 +2028,11 @@ class ElasticEngine:
                     # page for zeroed rows (every walk step maps to page 0,
                     # so Pallas elides the repeats).
                     window = self.api.cfg.sliding_window
+                    kernel = paged and self.attn_impl == "paged_kernel"
+                    if kernel and chunk_tok is not None:
+                        self._count_mq_rows(q_len_np, padded, execs)
                     for i in range(b):
-                        if not (paged and self.attn_impl == "paged_kernel"):
+                        if not kernel:
                             self._attn_tokens_read += self._attn_read_span
                         elif active[i] is not None:
                             self._attn_tokens_read += \
@@ -2134,6 +2142,18 @@ class ElasticEngine:
         vals = [r.slo.tpot_ms for r in reqs
                 if r.slo is not None and r.slo.tpot_ms is not None]
         return min(vals) if vals else None
+
+    def _count_mq_rows(self, q_len, c: int, execs: int) -> None:
+        """Account ``execs`` runs of one multi-query step whose rows carry
+        ``q_len`` live queries in a block of ``c`` lanes: the query rows
+        the MQ kernel folds per kv head (``mq_rows_folded``, the narrow
+        fold) against the ``B * C * G`` rows the block holds.
+        ``stats()["mq_rows_folded"] / stats()["mq_rows_padded"]`` is the
+        share of the padded fold that runs."""
+        g = self.api.cfg.n_heads // self.api.cfg.n_kv_heads
+        self._mq_rows_padded += execs * len(q_len) * c * g
+        self._mq_rows_folded += execs * sum(
+            mq_rows_folded(int(n), c, g) for n in q_len)
 
     def _record_tick(self, prefill_tokens: int, prefill_chunks: int,
                      decode: int, wall_s: float, *, execs: int = 0,
@@ -2399,6 +2419,8 @@ class ElasticEngine:
                 "ticks_replayed": self._ticks_replayed,
                 "admission_requeues": self._admission_requeues,
                 "attn_tokens_read": self._attn_tokens_read,
+                "mq_rows_folded": self._mq_rows_folded,
+                "mq_rows_padded": self._mq_rows_padded,
                 "spec_ticks": self._spec_ticks,
                 "spec_accepted": self._spec_accepted,
                 "spec_rejected": self._spec_rejected,
@@ -2488,6 +2510,8 @@ class ElasticEngine:
         self._ticks_replayed = c["ticks_replayed"]
         self._admission_requeues = c["admission_requeues"]
         self._attn_tokens_read = c["attn_tokens_read"]
+        self._mq_rows_folded = c.get("mq_rows_folded", 0)
+        self._mq_rows_padded = c.get("mq_rows_padded", 0)
         self._spec_ticks = c.get("spec_ticks", 0)
         self._spec_accepted = c.get("spec_accepted", 0)
         self._spec_rejected = c.get("spec_rejected", 0)
@@ -2579,6 +2603,8 @@ class ElasticEngine:
             "attn_tokens_read": self._attn_tokens_read,
             "attn_read_bytes": self._attn_tokens_read
             * self._attn_token_bytes,
+            "mq_rows_folded": self._mq_rows_folded,
+            "mq_rows_padded": self._mq_rows_padded,
             "admission_order": self.admission_order,
             "cost_model": (self.policy.cost.snapshot()
                            if self.policy.cost is not None else None),

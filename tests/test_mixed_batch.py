@@ -164,6 +164,63 @@ def test_pages_read_mq_collapses_to_pages_read():
                     pa.pages_read(L, ps, window), (ps, window, L)
 
 
+# The narrow fold: at a chunk wider than NARROW_LANES, rows with few live
+# lanes fold only the first NARROW_LANES * G query rows of the block. Rows
+# on both sides of the threshold, at q_len 0, and a whole-chunk row.
+WIDE_C = 32
+NARROW_ROWS = [(5, 0),       # no live lane
+               (17, 1),      # decode row
+               (8, 7),       # narrow, one lane short of the threshold
+               (3, 8),       # narrow, exactly at the threshold
+               (16, 9),      # one lane past it: the full fold
+               (9, WIDE_C)]  # the whole chunk
+
+
+@pytest.mark.parametrize("g", [2, 3])
+@pytest.mark.parametrize("window", [None, 10])
+@pytest.mark.parametrize("tq", [None, 16])
+def test_mq_narrow_fold_matches_gather_reference(window, tq, g):
+    q, kp, vp, bt, qo, ql = _mq_case(5, NARROW_ROWS, c=WIDE_C, g=g)
+    got = _mq_kernel(q, kp, vp, bt, qo, ql, window=window, tq=tq)
+    want = _mq_gather_ref(q, kp, vp, bt, qo, ql, window=window)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=1e-5, atol=1e-5)
+    for i, (_, ql_i) in enumerate(NARROW_ROWS):
+        assert np.all(np.asarray(got)[i, ql_i:] == 0), i
+
+
+def test_mq_rows_folded_follows_the_narrow_rule():
+    """The host mirror: per q block, nothing without a live lane,
+    NARROW_LANES * G rows up to NARROW_LANES live lanes, the whole block
+    past that — so every live lane is folded and no row-count exceeds the
+    padded ``c * g``."""
+    n = pa.NARROW_LANES
+    assert n == 8
+    for g in (1, 3, 4):
+        want = {0: 0, 1: n * g, 7: n * g, 8: n * g, 9: 32 * g, 32: 32 * g}
+        for q_len, rows in want.items():
+            assert pa.mq_rows_folded(q_len, 32, g) == rows, (q_len, g)
+        # two q blocks of 16: each block applies the rule to its own lanes
+        blocks = {0: 0, 8: n * g, 9: 16 * g, 16: 16 * g, 17: 16 * g + n * g,
+                  24: 16 * g + n * g, 25: 32 * g, 32: 32 * g}
+        for q_len, rows in blocks.items():
+            assert pa.mq_rows_folded(q_len, 32, g, tq=16) == rows, (q_len, g)
+        # a block no wider than NARROW_LANES always folds whole
+        assert pa.mq_rows_folded(5, 8, g) == 8 * g
+        assert pa.mq_rows_folded(5, 8, g, tq=4) == 8 * g
+        assert pa.mq_rows_folded(0, 8, g, tq=4) == 0
+        for c, tq in ((32, None), (32, 16), (64, None), (8, 4)):
+            for q_len in range(c + 1):
+                rows = pa.mq_rows_folded(q_len, c, g, tq=tq)
+                assert q_len * g <= rows <= c * g, (c, tq, q_len)
+    # the chat cell's full-width mixed tick: 15 decode rows + one chunk of 64
+    # at qwen3-4b's G = 4
+    share = (15 * pa.mq_rows_folded(1, 64, 4)
+             + pa.mq_rows_folded(64, 64, 4)) / (16 * 64 * 4)
+    assert share == pytest.approx((15 * 32 + 256) / (16 * 256))
+
+
 # =============================================================================
 # 2. Adversarial poison
 # =============================================================================
@@ -200,6 +257,26 @@ def test_mq_kernel_ignores_poisoned_pool(window):
     # the pool poisoned — the engine's sampler never sees them, but a NaN
     # there would poison the whole row through the output projection
     for i, (_, ql_i) in enumerate(BOUNDARY_ROWS):
+        assert np.all(dirty[i, ql_i:] == 0), i
+
+
+@pytest.mark.parametrize("window", [None, 10])
+@pytest.mark.parametrize("tq", [None, 16])
+def test_mq_narrow_fold_ignores_poisoned_pad_queries(window, tq):
+    """NaN in the pad lanes of the queries (every lane at or past each
+    row's q_len, in the narrow rows' folded extent and past it alike):
+    live lanes stay BIT-identical to the clean run, dead lanes exact
+    zeros."""
+    q, kp, vp, bt, qo, ql = _mq_case(6, NARROW_ROWS, c=WIDE_C, g=3)
+    clean = np.asarray(_mq_kernel(q, kp, vp, bt, qo, ql, window=window,
+                                  tq=tq))
+    q_p = np.array(q)
+    for i, (_, ql_i) in enumerate(NARROW_ROWS):
+        q_p[i, ql_i:] = np.nan
+    dirty = np.asarray(_mq_kernel(jnp.asarray(q_p), kp, vp, bt, qo, ql,
+                                  window=window, tq=tq))
+    assert np.array_equal(clean, dirty)
+    for i, (_, ql_i) in enumerate(NARROW_ROWS):
         assert np.all(dirty[i, ql_i:] == 0), i
 
 
@@ -400,6 +477,37 @@ def test_exhaustion_mid_chunk_requeues_not_leaks_mixed(setup):
     assert st["admission_requeues"] >= 1
     assert st["kv_pages_alloc"] == st["kv_pages_freed"]       # no leak
     assert [r.out_tokens for r in reqs] == [r.out_tokens for r in ref]
+
+
+def test_mq_rows_folded_counts_the_narrow_fold(setup):
+    """stats() accounts the MQ kernel's fold: at a chunk wider than
+    NARROW_LANES the decode rows of each mixed tick fold NARROW_LANES
+    lanes, so fewer rows fold than the block holds; with no MQ call
+    (sequential scheduler, gather impl) both counts stay equal, at 0. The
+    streams are the sequential scheduler's, token for token."""
+    cfg, api, params, anchor = setup
+    chunk = 4 * CHUNK
+    assert chunk > pa.NARROW_LANES
+    kw = dict(batch_slots=4, max_len=64, kv_layout="paged", kv_page_size=PS,
+              prefill_chunk=chunk)
+    reqs = lambda: _reqs(cfg, 5, max_new=4, plens=(40, 9, 21, 3), seed=3)
+
+    def run(scheduler, impl):
+        eng = _engine(api, anchor, params, scheduler=scheduler,
+                      attn_impl=impl, **kw)
+        rs = reqs()
+        eng.generate(rs, fmt_override="mxint8")
+        assert all(r.done for r in rs)
+        return [r.out_tokens for r in rs], eng.stats
+
+    mixed, st = run("mixed", "paged_kernel")
+    g = cfg.n_heads // cfg.n_kv_heads
+    assert 0 < st["mq_rows_folded"] < st["mq_rows_padded"], st
+    assert st["mq_rows_padded"] % (4 * chunk * g) == 0
+    for sched, impl in (("sequential", "paged_kernel"), ("mixed", "gather")):
+        streams, st0 = run(sched, impl)
+        assert st0["mq_rows_folded"] == st0["mq_rows_padded"] == 0
+        assert streams == mixed
 
 
 def test_scheduler_knob_validation(setup):

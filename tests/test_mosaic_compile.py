@@ -120,3 +120,21 @@ def test_paged_multi_query_kernel_compiles_for_v5e(one_chip, heads, window):
                  q, k, v, bt, qo, ql, window=window),
              _sds(one_chip, (B, CHUNK, h, d), jnp.bfloat16), pool, pool,
              i32(B, MAX_PAGES), i32(B), i32(B))
+
+
+@pytest.mark.parametrize("window", [None, 64])
+@pytest.mark.parametrize("heads", ["smollm", "qwen3"])
+def test_paged_multi_query_narrow_fold_compiles_for_v5e(one_chip, heads,
+                                                         window):
+    """At the serving chunk of 64 lanes both branches of the narrow fold
+    lower: NARROW_LANES * G query rows (24 at smollm-135m's G 3, 32 at
+    qwen3-4b's G 4) and the whole block of 64 * G."""
+    h, hkv, d = HEADS[heads]
+    c = 64
+    assert c > pa.NARROW_LANES
+    pool = _sds(one_chip, (PAGES, PS, hkv * d), jnp.bfloat16)
+    i32 = lambda *s: _sds(one_chip, s, jnp.int32)
+    _compile(lambda q, k, v, bt, qo, ql: pa.paged_attention_pallas_mq(
+                 q, k, v, bt, qo, ql, window=window),
+             _sds(one_chip, (B, c, h, d), jnp.bfloat16), pool, pool,
+             i32(B, MAX_PAGES), i32(B), i32(B))
