@@ -26,7 +26,7 @@ import shutil
 import sys
 import time
 from pathlib import Path
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -114,6 +114,7 @@ class RunData:
     pacer: Pacer
     tick_trace: List[dict]
     phases: Dict[str, float]
+    devices: Sequence[int] = (0,)  # ids of the cell's chips (the mesh's)
     trace: object = None          # bench.lib.trace.Reduced
     traced: Optional[dict] = None  # bench.lib.work.tick_totals over traced
 
@@ -174,6 +175,12 @@ class Session:
     runs one rate after another on one session). ``engine`` overrides the
     configuration's engine settings (the sweep tries other slots and
     chunks).
+
+    The engine block's ``tp`` (default 1) is the cell's tensor
+    parallelism: for ``tp > 1`` the anchor and the engine live on a
+    ``(1, tp)`` ``("data", "model")`` mesh over the first ``tp`` devices.
+    A workload whose ``chips`` is not ``tp`` is refused, so the mesh and
+    the chips the cell is given never disagree.
     """
 
     def __init__(self, workload: str, seed: int, *, root: Path = spec.ROOT,
@@ -195,8 +202,19 @@ class Session:
         self.eng_cfg = dict(self.cfg["engine"], **(engine or {}))
         self.model = self.cfg["model"]
         self.rung = rung or self.mix["rung"]
+        self.tp = tp = int(self.eng_cfg.get("tp", 1))
+        if c["workload"]["chips"] != tp:
+            raise ValueError(
+                f"workload {workload!r} asks for {c['workload']['chips']} "
+                f"chip(s) but its configuration's engine has tp {tp}")
         dev = jax.devices()
-        self.dev = dev[0]
+        self.mesh = None
+        if tp > 1:
+            from repro.launch.mesh import make_mesh
+            self.mesh = make_mesh((1, tp), ("data", "model"))
+            self.devs = list(self.mesh.devices.flat)
+        else:
+            self.devs = [dev[0]]
         self.device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
                        "count": len(dev)}
         self.peaks = peaks_for(dev[0].device_kind) if chip else None
@@ -208,22 +226,22 @@ class Session:
         e = self.eng_cfg
         self.slots, self.chunk = e["batch_slots"], e["prefill_chunk"]
         self.page = e["kv_page_size"]
+        mcfg = ModelConfig(name=self.cfg["name"], family="dense", **self.model)
+        api = get_model(mcfg, None)
+        template = jax.eval_shape(api.init_params, jax.random.PRNGKey(0))
         with self.phase("weights"):
-            self.anchor = make_anchor_on_device(self.model, seed, e["anchor"],
-                                                e["block_size"])
+            self.anchor = make_anchor_on_device(
+                self.model, seed, e["anchor"], e["block_size"],
+                mesh=self.mesh, api=api, template=template)
             jax.block_until_ready(self.anchor)
         with self.phase("engine"):
-            mcfg = ModelConfig(name=self.cfg["name"], family="dense",
-                               **self.model)
-            api = get_model(mcfg, None)
-            template = jax.eval_shape(api.init_params, jax.random.PRNGKey(0))
             self.eng = ElasticEngine(
                 api, self.anchor, batch_slots=self.slots,
                 max_len=e["max_len"], param_template=template,
                 kv_layout="paged", kv_page_size=self.page,
                 kv_num_pages=e.get("kv_num_pages"),
                 prefill_chunk=self.chunk, attn_impl="paged_kernel",
-                fused=True, seed=seed)
+                fused=True, seed=seed, mesh=self.mesh)
             if tamper is not None:
                 tamper(self.eng)
         with self.phase("conversion"):
@@ -258,12 +276,13 @@ class Session:
             self.eng.generate(requests, greedy=True,
                               fmt_override=self.rung, guard=pacer)
         return RunData(cell=self.c, shapes=Shapes.from_model(
-                           self.model, self.eng_cfg["block_size"]),
+                           self.model, self.eng_cfg["block_size"], self.tp),
                        slots=self.slots, chunk=self.chunk, page=self.page,
                        bits=rung_bits(self.rung), peaks=self.peaks,
                        arrivals=arrivals, requests=requests, pacer=pacer,
                        tick_trace=list(self.eng.tick_trace),
-                       phases=dict(self.phase.wall))
+                       phases=dict(self.phase.wall),
+                       devices=[d.id for d in self.devs])
 
     def close(self) -> None:
         """Free the program's device state."""
@@ -334,9 +353,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     log(f"setup_s {setup_s:.3f}: " + ", ".join(
         f"{k} {ph[k]:.3f}" for k in named) + f", ramp {ramp:.3f}, other "
         f"{setup_s - ramp - sum(ph[k] for k in named):.3f}")
-    mem = s.dev.memory_stats() or {}
-    device = dict(s.device, memory_peak_bytes=int(
-        mem.get("peak_bytes_in_use", 0)))
+    device = dict(s.device, memory_peak_bytes=max(
+        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for d in s.devs))
     summ = summarize(data, seconds, s.mix["rate_per_s"])
     result = {"correct": False, "attempted": len(summ["win"]),
               "failed": len(summ["failed"]), "metrics": {}, "device": device}
@@ -423,7 +442,7 @@ def _reduce_trace(data: RunData, tracer: Tracer, device: dict,
         log("trace: no xplane file")
         return
     t0 = time.perf_counter()
-    red = tr.load(path, (0.0, 0.0))
+    red = tr.load(path, (0.0, 0.0), data.devices)
     span = [e for e in red.host if e.name == tr.WINDOW_SPAN]
     if not span or not red.ops:
         log(f"trace: {len(span)} traced spans, device planes "

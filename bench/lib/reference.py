@@ -35,8 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib.weights import base_key, global_weights, layer_shapes, \
-    layer_weights
+from bench.lib.weights import base_key, global_normal, global_shapes, \
+    layer_shapes, layer_weights, scaled
 
 HI = jax.lax.Precision.HIGHEST
 PAD = 128            # sequences are padded to PAD * 2**k tokens
@@ -108,6 +108,8 @@ class Reference:
         self.block_size = block_size
         self.act_dtype = act_dtype
         self._layer_w = jax.jit(self._make_layer)
+        self._round = jax.jit(self._scale_round, static_argnums=1,
+                              donate_argnums=0)
         self._apply = jax.jit(self._apply_layer)
         self._gap_j = jax.jit(self._gap)
         self._first_j = jax.jit(self._first)
@@ -121,6 +123,17 @@ class Reference:
                                     self.block_size) \
                 if kinds[name] in ("proj", "out") else bf16(w)
         return out
+
+    def _scale_round(self, z, kind):
+        return bf16(scaled(z, kind, self.m["n_layers"]))
+
+    def _global(self, name: str) -> jax.Array:
+        """Global leaf ``name``, bf16 values held in float32. The draw's
+        own buffer takes the scaling and rounding (donated), so a (V, d)
+        leaf is held once, in float32; the values are those of the eager
+        ``bf16(global_weight(...))``."""
+        return self._round(global_normal(self.key, self.m, name),
+                           global_shapes(self.m)[name][1])
 
     def _dot(self, x, w):
         if self.act_dtype is not None:
@@ -184,8 +197,7 @@ class Reference:
     def hidden(self, seqs: Sequence[np.ndarray]) -> List[jax.Array]:
         """Last hidden states (T, d), before the final norm, of each
         sequence."""
-        glob = global_weights(self.key, self.m)
-        embed = bf16(glob["embed"])
+        embed = self._global("embed")
         xs = []
         for s in seqs:
             t = PAD
@@ -194,17 +206,23 @@ class Reference:
             toks = np.zeros(t, np.int32)
             toks[:len(s)] = s
             xs.append(jnp.take(embed, jnp.asarray(toks), axis=0))
-        del embed, glob
+        # Each step waits for the device: dispatch would run ahead and
+        # draw the next layers' float32 weights while this one's are held
+        # (at qwen2-72b widths the peak reached 16.2 of a v5e's 16.9 GB).
+        jax.block_until_ready(xs)
+        del embed
         for layer in range(self.m["n_layers"]):
             w = self._layer_w(self.key, layer)
-            xs = [self._apply(x, w) for x in xs]
+            xs = jax.block_until_ready([self._apply(x, w) for x in xs])
+            del w
         return [x[:len(s)] for x, s in zip(xs, seqs)]
 
     def head_weights(self):
-        glob = global_weights(self.key, self.m)
-        head = glob["embed"].T if self.m["tie_embeddings"] \
-            else glob["lm_head"]
-        return bf16(head), bf16(glob["final_norm"])
+        """(lm_head (d, V), final norm gain); only the leaf the head uses
+        is drawn: the embedding where they are tied, else the lm_head."""
+        head = self._global("embed").T if self.m["tie_embeddings"] \
+            else self._global("lm_head")
+        return head, self._global("final_norm")
 
 
 ROWS = 256           # lm_head rows per block

@@ -2,10 +2,11 @@
 
 The JAX profiler writes ``<dir>/plugins/profile/<run>/*.xplane.pb``;
 ``jax.profiler.ProfileData`` reads it. Device planes are named
-``/device:<KIND>:<n>``; on each, the line of XLA operations holds one event
-per operation that ran, with a start and a duration in nanoseconds. Host
-planes hold the driver's own ``TraceAnnotation`` spans (names starting
-with ``bench.``), on the same clock.
+``/device:<KIND>:<n>``, ``n`` the device's id; on each, the line of XLA
+operations holds one event per operation that ran, with a start and a
+duration in nanoseconds. Only the planes of the cell's own chips are kept.
+Host planes hold the harness's ``TraceAnnotation`` spans (names starting
+with ``bench.``) and the program's (``engine.``), on the same clock.
 """
 from __future__ import annotations
 
@@ -18,7 +19,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 Interval = Tuple[float, float]          # (start_ns, end_ns)
 
 OPS_LINE = re.compile(r"XLA Ops")
-HOST_PREFIX = "bench."
+DEVICE_PLANE = re.compile(r"^/device:[^:]+:(\d+)")
+HOST_PREFIX = ("bench.", "engine.")
 WINDOW_SPAN = "bench.traced"    # the traced window itself: names no gap
 HOLDER = re.compile(r"\s(while|conditional|call|[\w-]+-start)\(")
 # An op event's name is its HLO instruction, e.g.
@@ -33,6 +35,13 @@ CUSTOM_CALL = re.compile(r"= (.*?) custom-call\((.*?)\), "
 OPERAND_LAYOUTS = re.compile(
     r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}")
 TYPE = re.compile(r"\b(pred|bf16|f8e\w+|[fsu]\d+)\[([\d,]*)\]")
+# A collective's HLO opcode, synchronous or as an asynchronous pair, as
+# its instruction calls it (``all-reduce(``, ``all-gather-start(``) or as
+# the op's own name begins (``%all-reduce.3``, ``all-gather-done.1``).
+COLLECTIVES = r"(?:all-reduce|all-gather|reduce-scatter|collective-permute" \
+    r"|all-to-all)(?:-start|-done)?"
+COLLECTIVE_CALL = re.compile(r"\s" + COLLECTIVES + r"\(")
+COLLECTIVE_NAME = re.compile(r"^%?" + COLLECTIVES + r"(?:[.\-]|$)")
 Type = Tuple[str, Tuple[int, ...]]
 
 
@@ -59,8 +68,8 @@ def _text(e) -> str:
 @dataclasses.dataclass
 class Reduced:
     """What the readers need from one trace."""
-    ops: Dict[str, List[Event]]           # device plane -> op events
-    host: List[Event]                      # the driver's spans
+    ops: Dict[str, List[Event]]           # the cell's device plane -> ops
+    host: List[Event]                      # the harness's and engine's spans
     window: Interval                       # host clock, ns
 
 
@@ -70,13 +79,27 @@ def find_xplane(trace_dir: str) -> Optional[str]:
     return files[-1] if files else None
 
 
-def load(path: str, window: Interval) -> Reduced:
+def load(path: str, window: Interval, devices: Sequence[int]) -> Reduced:
     from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+    return reduce_planes(ProfileData.from_file(path).planes, window, devices)
+
+
+def device_id(plane: str) -> Optional[int]:
+    """The device id of a device plane's name, or None."""
+    m = DEVICE_PLANE.match(plane)
+    return int(m.group(1)) if m else None
+
+
+def reduce_planes(planes, window: Interval,
+                  devices: Sequence[int]) -> Reduced:
+    """The op events of the device planes of ``devices`` and the host
+    spans, from ``ProfileData`` planes."""
     ops: Dict[str, List[Event]] = {}
     host: List[Event] = []
-    for plane in pd.planes:
+    for plane in planes:
         if plane.name.startswith("/device:"):
+            if device_id(plane.name) not in devices:
+                continue
             evs = []
             for line in plane.lines:
                 if OPS_LINE.search(line.name):
@@ -153,6 +176,14 @@ def kernel_ns(events: Sequence[Event], match: Callable[[str], bool],
     statistics) ``match`` accepts."""
     return sum(e - s for ev in events if match(ev.text)
                for s, e in clip([(ev.start, ev.end)], window))
+
+
+def is_collective(text: str) -> bool:
+    """Whether an op's text is a collective between chips: all-reduce,
+    all-gather, reduce-scatter, collective-permute or all-to-all, alone or
+    the start or done of an asynchronous one."""
+    return bool(COLLECTIVE_NAME.match(text)
+                or COLLECTIVE_CALL.search(text.partition("), ")[0]))
 
 
 def _fmt(t: Type) -> str:

@@ -61,13 +61,17 @@ def layer_shapes(m: dict) -> Dict[str, Tuple[Tuple[int, ...], str]]:
     return out
 
 
-def _draw(key, shape, kind, n_layers):
-    z = jax.random.normal(key, shape, jnp.float32)
+def scaled(z, kind: str, n_layers: int):
+    """A standard normal draw ``z`` at the scale of a leaf of ``kind``."""
     if kind == "gain":
         return 1.0 + GAIN_STD * z
     if kind == "out":
         return (STD / n_layers ** 0.5) * z
     return STD * z
+
+
+def _draw(key, shape, kind, n_layers):
+    return scaled(jax.random.normal(key, shape, jnp.float32), kind, n_layers)
 
 
 def layer_weights(key, m: dict, layer) -> Dict[str, jax.Array]:
@@ -76,13 +80,29 @@ def layer_weights(key, m: dict, layer) -> Dict[str, jax.Array]:
             for name, (shape, kind) in layer_shapes(m).items()}
 
 
-def global_weights(key, m: dict) -> Dict[str, jax.Array]:
-    """float32 embedding (V, d), final norm gain (d,) and, unless the
-    embeddings are tied, lm_head (d, V)."""
+def global_shapes(m: dict) -> Dict[str, Tuple[Tuple[int, ...], str]]:
+    """name -> (shape, kind) of the embedding (V, d), the final norm gain
+    (d,) and, unless the embeddings are tied, the lm_head (d, V)."""
     d, v = m["d_model"], m["vocab"]
-    out = {"embed": STD * jax.random.normal(_key(key, "embed"), (v, d)),
-           "final_norm": 1.0 + GAIN_STD * jax.random.normal(
-               _key(key, "final_norm"), (d,))}
+    out = {"embed": ((v, d), "proj"), "final_norm": ((d,), "gain")}
     if not m["tie_embeddings"]:
-        out["lm_head"] = STD * jax.random.normal(_key(key, "lm_head"), (d, v))
+        out["lm_head"] = ((d, v), "proj")
     return out
+
+
+def global_normal(key, m: dict, name: str) -> jax.Array:
+    """The standard normal draw behind global leaf ``name``."""
+    return jax.random.normal(_key(key, name), global_shapes(m)[name][0],
+                             jnp.float32)
+
+
+def global_weight(key, m: dict, name: str) -> jax.Array:
+    """One float32 global leaf; each has its own key, so one can be drawn
+    without the others."""
+    return scaled(global_normal(key, m, name), global_shapes(m)[name][1],
+                  m["n_layers"])
+
+
+def global_weights(key, m: dict) -> Dict[str, jax.Array]:
+    """Every float32 global leaf (``global_shapes``)."""
+    return {name: global_weight(key, m, name) for name in global_shapes(m)}

@@ -36,9 +36,9 @@ MIX = {"name": "chat.mxint8", "rate_per_s": 3.0, "ramp_s": 1,
 SEED = 2 ** 31 + 3
 
 
-@pytest.fixture(scope="module")
-def root(tmp_path_factory):
-    r = tmp_path_factory.mktemp("bench_root")
+def make_root(r: Path, tp: int = 1, chips: int = None) -> Path:
+    """A checkout root that holds the small cell, on a ``(1, tp)`` mesh
+    over ``chips`` chips (``tp`` where None)."""
     (r / "bench").mkdir()
     shutil.copytree(BENCH / "metrics", r / "bench" / "metrics")
     for d in ("configs", "traffic", "limits"):
@@ -47,17 +47,29 @@ def root(tmp_path_factory):
     doc["configs"] = [{"name": "small", "source": "x", "reduced": [],
                        "file": "bench/configs/small.json", "why": "test"}]
     doc["workloads"] = [{"name": "small.chat.mxint8", "config": "small",
-                         "traffic": "chat.mxint8", "chips": 1,
+                         "traffic": "chat.mxint8",
+                         "chips": tp if chips is None else chips,
                          "why": "test"}]
     for m in doc["per_layer"]:
         m["workloads"] = ["small.chat.mxint8"]
     (r / "BENCHMARK.json").write_text(json.dumps(doc))
-    (r / "bench" / "configs" / "small.json").write_text(json.dumps(CFG))
+    cfg = dict(CFG, engine=dict(CFG["engine"], tp=tp)) if tp > 1 else CFG
+    (r / "bench" / "configs" / "small.json").write_text(json.dumps(cfg))
     (r / "bench" / "traffic" / "chat.mxint8.json").write_text(
         json.dumps(MIX))
     (r / "bench" / "limits" / "small.chat.mxint8.json").write_text(
         json.dumps({"logit_gap": {"limit": 0.008}}))
     return r
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    return make_root(tmp_path_factory.mktemp("bench_root"))
+
+
+@pytest.fixture(scope="module")
+def root_tp2(tmp_path_factory):
+    return make_root(tmp_path_factory.mktemp("bench_root_tp2"), tp=2)
 
 
 def run(root, **kw):
@@ -115,6 +127,67 @@ def test_the_float8_control_fails(root):
 
 def test_the_program_at_the_lower_rung_fails(root):
     assert run(root, rung="mxint4", ref_rung="mxint8")["correct"] is False
+
+
+def test_a_run_on_a_tp2_mesh_is_correct(root_tp2):
+    import jax
+    assert len(jax.devices()) >= 2
+    r = run(root_tp2)
+    assert r["correct"] is True, r["checks"]
+    assert r["attempted"] > 0 and r["failed"] == 0
+    assert set(r["metrics"]) == {"ttft_p90_ms", "itl_p95_ms", "setup_s"}
+
+
+def test_a_tp2_mesh_serves_the_tokens_of_one_device(root, root_tp2):
+    """The harness's sharded anchor and meshed engine serve, request for
+    request, the tokens of the one-device engine (greedy, one wave), up to
+    a tie that rounding decides: in bfloat16 a row-parallel projection's
+    partial sums are rounded before their psum, so the two engines' logits
+    differ by a few thousandths. Where a stream departs, the one-device
+    engine's logits of the two tokens lie closer together than the two
+    engines' logits lie apart: that is the criterion.
+
+    Readings (CPU, 8 requests): at this seed 4 streams depart, at seeds
+    2**31+101 and +207 3 and 2, each at a top-2 gap of 0.0003-0.0028
+    under a logit difference of 0.0037-0.0059. With the row-parallel
+    partials summed in float32 (a copy of the program so changed), all 8
+    streams agree on all three seeds: rounding, not sharding, parts them.
+    The floor of half the streams whole is the fewest those seeds read;
+    a path that departed more often than near-ties explain fails it even
+    where each departure passes."""
+    import numpy as np
+    from bench.lib.traffic import make_arrivals
+    sessions = [cell.Session("small.chat.mxint8", SEED, root=r, chip=False)
+                for r in (root, root_tp2)]
+    assert [s.tp for s in sessions] == [1, 2]
+    arrivals = make_arrivals(sessions[0].mix, CFG["model"]["vocab"], SEED,
+                             [1, 3, 1])[:8]
+    streams = []
+    for s in sessions:
+        reqs = [s.Request(rid=i, prompt=a.prompt, max_new=a.max_new)
+                for i, a in enumerate(arrivals)]
+        s.eng.generate(reqs, greedy=True, fmt_override=s.rung)
+        streams.append([list(q.out_tokens) for q in reqs])
+    one, tp2 = streams
+    assert all(one) and [len(t) for t in one] == [len(t) for t in tp2]
+    same = 0
+    for a, x, y in zip(arrivals, one, tp2):
+        j = next((j for j, (u, v) in enumerate(zip(x, y)) if u != v), None)
+        if j is None:
+            same += 1
+            continue
+        prefix = np.concatenate([a.prompt, np.asarray(x[:j], np.int32)])
+        l1, l2 = (s.eng.prompt_logits(prefix, s.rung) for s in sessions)
+        assert abs(l1[x[j]] - l1[y[j]]) < np.max(np.abs(l1 - l2)) < 0.02
+    assert same >= len(arrivals) // 2
+    for s in sessions:
+        s.close()
+
+
+def test_a_cell_whose_chips_are_not_its_tp_is_refused(tmp_path):
+    r = make_root(tmp_path, tp=2, chips=1)
+    with pytest.raises(ValueError, match="tp 2"):
+        cell.Session("small.chat.mxint8", SEED, root=r, chip=False)
 
 
 def test_run_exits_nonzero_without_a_chip():

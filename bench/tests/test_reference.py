@@ -101,3 +101,83 @@ def test_forward_matches_the_program_in_float32(m):
                                          cache)
         np.testing.assert_allclose(np.asarray(got[0]), want[t - 1],
                                    rtol=2e-4, atol=2e-5)
+
+
+def _old_global(key, m):
+    """The global leaves as they were first drawn: all three at once,
+    eagerly, in float32."""
+    from bench.lib.weights import GAIN_STD, STD, _key
+    d, v = m["d_model"], m["vocab"]
+    out = {"embed": STD * jax.random.normal(_key(key, "embed"), (v, d)),
+           "final_norm": 1.0 + GAIN_STD * jax.random.normal(
+               _key(key, "final_norm"), (d,))}
+    if not m["tie_embeddings"]:
+        out["lm_head"] = STD * jax.random.normal(_key(key, "lm_head"), (d, v))
+    return out
+
+
+@pytest.mark.parametrize("m", [SWIGLU, GELU], ids=["untied", "tied"])
+def test_global_leaves_one_at_a_time_keep_their_values(m, monkeypatch):
+    """Drawn one leaf at a time, and rounded in the draw's own buffer, the
+    global leaves hold the values they always held, so ``logit_gap``
+    readings do not move; the head draws only the leaf it uses."""
+    from bench.lib import reference
+    from bench.lib.reference import bf16
+    seed = 2 ** 31 + 77
+    old = _old_global(base_key(seed), m)
+    new = global_weights(base_key(seed), m)
+    assert list(new) == list(old)
+    for k in old:
+        np.testing.assert_array_equal(np.asarray(new[k]), np.asarray(old[k]))
+    ref = Reference(m, seed, anchor_bits=8, bits=8)
+    drawn = []
+    real = reference.global_normal
+    monkeypatch.setattr(reference, "global_normal",
+                        lambda key, mm, name: drawn.append(name)
+                        or real(key, mm, name))
+    head, fnorm = ref.head_weights()
+    want = old["embed"].T if m["tie_embeddings"] else old["lm_head"]
+    np.testing.assert_array_equal(np.asarray(head), np.asarray(bf16(want)))
+    np.testing.assert_array_equal(np.asarray(fnorm),
+                                  np.asarray(bf16(old["final_norm"])))
+    assert drawn == ["embed" if m["tie_embeddings"] else "lm_head",
+                     "final_norm"]
+    np.testing.assert_array_equal(np.asarray(ref._global("embed")),
+                                  np.asarray(bf16(old["embed"])))
+
+
+def test_anchor_on_a_mesh_is_the_unsharded_anchor():
+    """Made on a (1, 2) mesh, each leaf is split as the engine serves it
+    and holds the unsharded anchor's values bit for bit."""
+    from repro.launch.mesh import make_mesh
+    from repro.models import get_model
+    from repro.models.common import ModelConfig
+    assert len(jax.devices()) >= 2      # the root conftest pins 2 on the CPU
+    m = dict(SWIGLU, qkv_bias=True, d_model=128, head_dim=32, d_ff=256)
+    seed = 2 ** 32 + 9
+    one = make_anchor_on_device(m, seed)
+    mesh = make_mesh((1, 2), ("data", "model"))
+    api = get_model(ModelConfig(name="small", family="dense", **m), None)
+    template = jax.eval_shape(api.init_params, jax.random.PRNGKey(0))
+    two = make_anchor_on_device(m, seed, mesh=mesh, api=api,
+                                template=template)
+    assert sorted(two.quantized) == sorted(one.quantized)
+    assert sorted(two.raw) == sorted(one.raw)
+    split = {"wq": 2, "wk": 2, "wv": 2, "w_gate": 2, "w_up": 2,
+             "wo": 1, "w_down": 1}          # the axis tensor parallelism cuts
+    for k, t in one.quantized.items():
+        u = two.quantized[k]
+        np.testing.assert_array_equal(u.codes, t.codes)
+        np.testing.assert_array_equal(u.scale_exp, t.scale_exp)
+        assert u.block_axis == t.block_axis and u.fmt == t.fmt
+        ax = split[k.split("'")[-2]]
+        assert u.codes.sharding.spec[ax] == "model"
+        for sh in u.codes.addressable_shards:
+            assert sh.data.shape[ax] == t.codes.shape[ax] // 2
+    for k, w in one.raw.items():
+        np.testing.assert_array_equal(np.asarray(two.raw[k], np.float32),
+                                      np.asarray(w, np.float32))
+        assert two.raw[k].dtype == w.dtype
+    # the embedding and the lm_head split along the vocabulary
+    assert two.raw["['embed']"].sharding.spec[0] == "model"
+    assert two.raw["['lm_head']"].sharding.spec[1] == "model"

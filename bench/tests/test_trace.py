@@ -93,3 +93,97 @@ def test_top_ops():
                   ev("%copy-start.3 = (bf16[2560], bf16[2560], u32[]) "
                      "copy-start(bf16[2560] %g)", 0, 100)]
     assert dict(tr.top_ops(held, WINDOW)) == top
+
+
+# ---- the cell's own chips, the program's spans, collectives ---------------
+def plane(name, lines):
+    """A ``ProfileData`` plane: ``lines`` maps a line name to (name,
+    start_ns, end_ns) events."""
+    from types import SimpleNamespace as NS
+    return NS(name=name, lines=[
+        NS(name=ln, events=[NS(name=n, start_ns=float(s), end_ns=float(e),
+                               stats={}) for n, s, e in evs])
+        for ln, evs in lines.items()])
+
+
+ALL_REDUCE = ('%all-reduce.7 = bf16[16,2560]{1,0} all-reduce(bf16[16,2560]'
+              '{1,0} %fusion.3), channel_id=1, replica_groups={{0,1,2,3}}, '
+              'to_apply=%add.1')
+GATHER_START = ('%all-gather-start.2 = (bf16[16,38016]{1,0}, bf16[16,'
+                '151936]{1,0}) all-gather-start(bf16[16,38016]{1,0} '
+                '%convolution.2), channel_id=2, dimensions={1}')
+GATHER_DONE = ('%all-gather-done.2 = bf16[16,151936]{1,0} all-gather-done(('
+               'bf16[16,38016]{1,0}, bf16[16,151936]{1,0}) '
+               '%all-gather-start.2)')
+NOT_COLLECTIVE = [GEMM8, ATTN, '%fusion.9 = bf16[16,2560]{1,0} fusion('
+                  'bf16[16,2560]{1,0} %all-reduce.7), kind=kLoop',
+                  '%copy-start.3 = (bf16[2560], bf16[2560], u32[]) '
+                  'copy-start(bf16[2560] %g)'] + OTHER
+
+
+def test_planes_outside_the_cells_devices_are_ignored():
+    planes = [plane("/device:TPU:0", {"XLA Ops": [(GEMM8, 0, 10)]}),
+              plane("/device:TPU:1", {"XLA Ops": [(GEMM8, 0, 90)]}),
+              plane("/device:TPU:2", {"XLA Ops": [(ATTN, 5, 50)]}),
+              plane("/host:CPU", {"python": [
+                  ("bench.traced", 0, 100), ("engine.tick", 0, 60),
+                  ("engine.fetch", 20, 30), ("jit_mixed_step", 5, 8)]})]
+    red = tr.reduce_planes(planes, WINDOW, [0])
+    assert list(red.ops) == ["/device:TPU:0"]
+    # the harness's and the program's spans, nothing else of the host
+    assert [e.name for e in red.host] == ["bench.traced", "engine.tick",
+                                          "engine.fetch"]
+    two = tr.reduce_planes(planes, WINDOW, [0, 2])
+    assert sorted(two.ops) == ["/device:TPU:0", "/device:TPU:2"]
+    assert tr.device_id("/device:TPU:3") == 3
+    assert tr.device_id("/host:CPU") is None
+
+
+def test_idle_gaps_name_the_innermost_engine_phase():
+    host = HOST + [ev("engine.tick", 25, 100), ev("engine.retire", 28, 45),
+                   ev("engine.fetch", 71, 89)]
+    gaps = dict((round(s * 1e9), n) for n, s in
+                tr.top_gaps(OPS, sorted(host, key=lambda e: e.start),
+                            WINDOW))
+    # 70-90: bench.hook (72-88) is shorter than engine.fetch (71-89)
+    assert gaps == {20: "bench.hook", 10: "engine.retire",
+                    5: "engine.tick"}
+
+
+def test_collectives_by_opcode():
+    assert tr.is_collective(ALL_REDUCE)
+    assert tr.is_collective(GATHER_START) and tr.is_collective(GATHER_DONE)
+    assert tr.is_collective("all-reduce.3") and tr.is_collective(
+        "reduce-scatter.1") and tr.is_collective("collective-permute-done")
+    assert tr.is_collective("all-reduce-scatter-fusion.2")
+    # an op whose operand is a collective's result is none itself
+    assert not any(tr.is_collective(t) for t in NOT_COLLECTIVE)
+
+
+def test_collective_share_over_the_cells_chips():
+    from types import SimpleNamespace as NS
+    from bench.metrics.collective_share import read
+    ops = {"/device:TPU:0": [ev(GEMM8, 0, 40), ev(ALL_REDUCE, 40, 50),
+                             ev(GATHER_START, 60, 61),
+                             ev(GATHER_DONE, 61, 70)],
+           "/device:TPU:1": [ev(GEMM8, 0, 45), ev(ALL_REDUCE, 45, 50),
+                             ev(GATHER_DONE, 95, 120)]}
+    red = tr.Reduced(ops=ops, host=[], window=WINDOW)
+    # chip 0: 10 + 1 + 9 = 20 ns; chip 1: 5 + 5 (clipped at 100) = 10 ns;
+    # averaged over the two chips, over the 100 ns window
+    assert read(NS(trace=red)) == 15.0
+    # one chip, no collective: no reading (never 0)
+    one = tr.Reduced(ops={"/device:TPU:0": OPS}, host=[], window=WINDOW)
+    assert read(NS(trace=one)) is None
+    assert read(NS(trace=None)) is None
+
+
+def test_step_mfu_divides_by_the_cells_chips():
+    from types import SimpleNamespace as NS
+    from bench.metrics.step_mfu import read
+    red = tr.Reduced(ops={}, host=[], window=(0.0, 2e9))
+    run = NS(traced={"model_flops": 1e12}, trace=red,
+             peaks={"flops_bf16": 1e12}, shapes=NS(tp=1))
+    assert read(run) == 50.0
+    run.shapes.tp = 4
+    assert read(run) == 12.5

@@ -67,3 +67,59 @@ def test_tick_totals():
                                   ((10, 1), (20, 1), (0, 12))) \
         + 3 * 2 * 64 * 512
     assert out["model_flops"] == pytest.approx(want)
+
+
+TICKS = [TickWork("decode", 16, [(99, 1, True), (1500, 1, True),
+                                 (37, 1, True)]),
+         TickWork("mixed", 1024, [(64, 64, False), (200, 1, True),
+                                  (900, 1, True)]),
+         TickWork("chunk", 32, [(0, 20, True)])]
+
+
+def test_one_chip_counts_are_unchanged():
+    """At tp 1 the counts are those the one-chip yardstick always gave,
+    exactly (worked out before tensor parallelism was counted)."""
+    out = tick_totals(Shapes(**QWEN3_4B), TICKS, 8, 16, 197e12, 819e9)
+    assert out == {"gemm_least_s": 0.04716354307698555,
+                   "gemm_flops": 7789829160960.0,
+                   "gemm_bytes": 15666610176.0,
+                   "gemm_compute_bound_s": 0.037771730153908625,
+                   "attn_least_s": 0.0005739801318681318,
+                   "attn_compute_bound_s": 0.0,
+                   "model_flops": 656781017088.0,
+                   "real_tokens": 89.0, "positions": 1072.0}
+    assert Shapes(**QWEN3_4B, tp=4).matmul_params == \
+        Shapes(**QWEN3_4B).matmul_params
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+def test_per_chip_shards_sum_to_the_whole(tp):
+    one, many = Shapes(**QWEN3_4B), Shapes(**QWEN3_4B, tp=tp)
+    # column-parallel (K, N/tp), row-parallel (K/tp, N)
+    assert many.projections() == [
+        (2560, 4096 // tp), (2560, 1024 // tp), (2560, 1024 // tp),
+        (4096 // tp, 2560), (2560, 9728 // tp), (2560, 9728 // tp),
+        (9728 // tp, 2560)]
+    # operations and weight bytes (codes and scales: no rows, M = 0) of
+    # every chip's shard add up to the whole projection's
+    for (k, n), (kc, nc) in zip(one.projections(), many.projections()):
+        f, b = gemm_work(16, k, n, 8, 32)
+        fc, bc = gemm_work(16, kc, nc, 8, 32)
+        assert fc * tp == f
+        assert gemm_work(0, kc, nc, 8, 32)[1] * tp == \
+            gemm_work(0, k, n, 8, 32)[1]
+    # attention over n_heads/tp and n_kv_heads/tp heads, the same keys
+    for off, q in ((99, 1), (16, 3), (0, 64)):
+        f, b = attn_work(one, off, q, 16)
+        fc, bc = attn_work(many, off, q, 16)
+        assert (fc * tp, bc * tp) == (f, b)
+    a = tick_totals(one, TICKS, 8, 16, 197e12, 819e9)
+    c = tick_totals(many, TICKS, 8, 16, 197e12, 819e9)
+    for k in ("gemm_flops", "model_flops", "real_tokens", "positions"):
+        assert c[k] == a[k], k
+    # the same work spread over tp chips: attention's least time summed
+    # over the chips is one chip's; the GEMMs' reads of their activations
+    # repeat on every chip, so theirs is no less
+    assert c["attn_least_s"] == pytest.approx(a["attn_least_s"])
+    assert c["gemm_least_s"] >= a["gemm_least_s"]
+    assert c["gemm_bytes"] > a["gemm_bytes"]
