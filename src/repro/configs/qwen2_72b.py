@@ -1,4 +1,7 @@
-"""qwen2-72b — exact assigned config (see repo prompt; [source] in DESIGN.md)."""
+"""qwen2-72b — Qwen2-72B as published (https://huggingface.co/Qwen/Qwen2-72B).
+
+head_dim is derived (8192 / 64 = 128); the lm_head is untied.
+"""
 from repro.models.common import ModelConfig
 import dataclasses
 

@@ -118,6 +118,14 @@ def tile_cache() -> Dict:
     return dict(_TILE_CACHE)
 
 
+# Weights laid out ahead of time, by their padded (K, N, block_size, fmt,
+# kind): the (tn, tk) they were padded for (``pin_layout``). The heuristic
+# re-derived from a padded width need not be that tile: qwen2-72b's d_ff
+# share 7392 is padded to 7424 for tn 256, and 7424, a lane multiple, would
+# be read at tn 128.
+_LAID_OUT: Dict[Tuple[int, int, int, str, str], Tuple[int, int]] = {}
+
+
 def _round_up(v: int, m: int) -> int:
     return -(-v // m) * m
 
@@ -156,16 +164,86 @@ def tiles_aligned(m: int, k: int, n: int, tiles: Tuple[int, int, int],
     return tm > 0 and tm % _SUBLANE == 0 and tn_ok and tk_ok
 
 
+def weight_tiles(k: int, n: int, fmt: MXFormat,
+                 kind: str = "mx") -> Tuple[int, int]:
+    """(tn, tk) of the heuristic: the tiles a weight of (K, N) is read
+    with when no autotuned entry applies. They do not depend on M, so they
+    are also the rule a weight is laid out by ahead of time
+    (``weight_layout``).
+
+    tn: a lane multiple, or the whole N when it is small and not
+    lane-aligned (unpacked kind only); among lane multiples up to the cap,
+    the one that pads N least. tk: a ``_k_unit(bs)`` multiple that pads K
+    least, or the whole K when that is small and would otherwise pad.
+    A shape ``pin_layout`` recorded is read with the tiles it was padded
+    for.
+    """
+    bs = fmt.block_size
+    pinned = _LAID_OUT.get((k, n, bs, fmt.name, kind))
+    if pinned is not None:
+        return pinned
+    n_eff = n // 2 if kind == "int4" else n
+    if n_eff % _LANE and kind != "int4" and n_eff <= _TN_WHOLE_CAP:
+        tn = n_eff
+    else:
+        tn = _LANE if n_eff % _LANE == 0 else _best_tile(n_eff, _LANE,
+                                                         _TN_CAP)
+    unit = _k_unit(bs)
+    kw = _round_up(k, bs)
+    tk = _best_tile(kw, unit, max(unit, _TK_CAP))
+    if kw <= _TK_WHOLE_CAP and _round_up(kw, tk) > kw:
+        tk = kw                   # one whole-K tile beats padding K
+    return tn, tk
+
+
+def weight_layout(k: int, n: int, fmt: MXFormat,
+                  kind: str = "mx") -> Tuple[int, int]:
+    """(K, N) a weight of (k, n) is padded to with zero codes so that the
+    tiles ``weight_tiles`` picks for (k, n) divide it (``pin_layout`` then
+    makes them the tiles the padded weight is read with): the wrapper
+    below pads no weight operand inside a step. For ``kind="int4"``, N
+    counts unpacked columns (twice the split-N byte columns). A width that
+    already needs no pad is returned as it is.
+    """
+    tn, tk = weight_tiles(k, n, fmt, kind)
+    kp = _round_up(_round_up(k, fmt.block_size), tk)
+    np_ = 2 * _round_up(n // 2, tn) if kind == "int4" else _round_up(n, tn)
+    return kp, np_
+
+
+def pin_layout(k: int, n: int, kp: int, np_: int, fmt: MXFormat,
+               kind: str = "mx") -> Tuple[int, int]:
+    """Record that a weight of (k, n) is served padded to (kp, np_): from
+    now on (kp, np_) is read with the tiles it was padded for, those of
+    (k, n), where they divide it. Returns the (tn, tk) it is read with."""
+    tn, tk = weight_tiles(k, n, fmt, kind)
+    n_eff = np_ // 2 if kind == "int4" else np_
+    if (kp, np_) != (k, n) and n_eff % tn == 0 and kp % tk == 0:
+        _LAID_OUT[(kp, np_, fmt.block_size, fmt.name, kind)] = (tn, tk)
+    return weight_tiles(kp, np_, fmt, kind)
+
+
 def select_tiles(m: int, k: int, n: int, fmt: MXFormat,
                  kind: str = "mx") -> Tuple[int, int, int]:
     """(tm, tn, tk) for an (M, K, N) qmatmul at ``fmt``.
 
-    Autotuned entries win; otherwise tiles are picked to minimize zero
+    Autotuned entries win; otherwise tm is the sublane multiple that pads M
+    least and (tn, tk) come from ``weight_tiles``, which minimize zero
     padding subject to VMEM-friendly caps and the Mosaic block rules of
-    ``tiles_aligned``: sublane multiples for M; lane multiples for N, or
-    the whole N when it is small and not lane-aligned; ``_k_unit(bs)``
-    multiples for K, or the whole K when it is small — so both the
-    activation block and the scale block tile.
+    ``tiles_aligned``: lane multiples for N, or the whole N when it is small
+    and not lane-aligned; ``_k_unit(bs)`` multiples for K, or the whole K
+    when it is small — so both the activation block and the scale block
+    tile.
+
+    Where a weight's N or K is not a multiple of its tile, the wrapper pads
+    the weight's codes and scales on every call, and a pad cannot fuse into
+    the Mosaic call: a copy of the weight each step. So a weight that is
+    served sharded is laid out once, when it is packed, at
+    ``weight_layout``'s widths (``serve.packed_params.layout_tp_shards``;
+    docs/serving_internals.md §11), and ``pin_layout`` makes the tiles it
+    was padded for the ones it is read with. An autotuned entry for such a
+    shape must divide the laid-out widths too, or it brings the per-call
+    pad back.
 
     ``(m, k, n)`` are whatever shapes this trace actually sees — per-shard
     local dims inside shard_map — and the lookup keys on them plus
@@ -179,19 +257,8 @@ def select_tiles(m: int, k: int, n: int, fmt: MXFormat,
     if key in _TILE_CACHE and tiles_aligned(m, k, n, _TILE_CACHE[key], bs,
                                             kind):
         return _TILE_CACHE[key]
-    n_eff = n // 2 if kind == "int4" else n
-    tm = _best_tile(m, _SUBLANE, _TM_CAP)
-    if n_eff % _LANE and kind != "int4" and n_eff <= _TN_WHOLE_CAP:
-        tn = n_eff
-    else:
-        tn = _LANE if n_eff % _LANE == 0 else _best_tile(n_eff, _LANE,
-                                                         _TN_CAP)
-    unit = _k_unit(bs)
-    kw = _round_up(k, bs)
-    tk = _best_tile(kw, unit, max(unit, _TK_CAP))
-    if kw <= _TK_WHOLE_CAP and _round_up(kw, tk) > kw:
-        tk = kw                   # one whole-K tile beats padding K
-    return tm, tn, tk
+    tn, tk = weight_tiles(k, n, fmt, kind)
+    return _best_tile(m, _SUBLANE, _TM_CAP), tn, tk
 
 
 # ---------------------------------------------------------------------------

@@ -187,7 +187,9 @@ class QuantCtx:
         ``tp_reduce=True`` marks a row-parallel projection: under tensor
         parallelism (``tp_axis`` set) the shard-local partial product is
         psum'd over the mesh axis BEFORE the bias add, so the (replicated)
-        bias is applied exactly once.
+        bias is applied exactly once. The psum runs under the named scope
+        ``tp.exchange/<projection>`` (``wo``, ``w_down``), which a device
+        trace shows on its ops.
         """
         if self.qmm is not None and is_packed_leaf(w):
             y = self.qmm(x, w, name)
@@ -197,7 +199,8 @@ class QuantCtx:
             y = jax.lax.dot_general(x, wq, (((x.ndim - 1,), (0,)), ((), ())),
                                     preferred_element_type=x.dtype)
         if tp_reduce and self.tp_axis is not None:
-            y = jax.lax.psum(y, self.tp_axis)
+            with jax.named_scope(f"tp.exchange/{name.rsplit('.', 1)[-1]}"):
+                y = jax.lax.psum(y, self.tp_axis)
         if b is not None:
             y = y + b.astype(x.dtype)
         if out_logical is not None:

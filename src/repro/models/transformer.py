@@ -407,7 +407,9 @@ def _embed(params, cfg: ModelConfig, tokens, tp_axis=None):
         ok = (local >= 0) & (local < v_local)
         rows = jnp.take(emb, jnp.where(ok, local, 0), axis=0)
         x = jnp.where(ok[..., None], rows, jnp.zeros((), emb.dtype))
-        x = jax.lax.psum(x, tp_axis).astype(cfg.compute_dtype)
+        with jax.named_scope("tp.exchange/embed"):
+            x = jax.lax.psum(x, tp_axis)
+        x = x.astype(cfg.compute_dtype)
         return shard_act(x, ("batch", None, None))
     x = jnp.take(emb, tokens, axis=0).astype(cfg.compute_dtype)
     return shard_act(x, ("batch", None, None))
@@ -440,8 +442,9 @@ def _head_logits(ctx: QuantCtx, params, cfg: ModelConfig, h_last):
             _lm_head_w(params, cfg).astype(jnp.float32),
             (((1,), (0,)), ((), ())))
     if ctx.tp_axis is not None and logits.shape[-1] != cfg.vocab:
-        logits = jax.lax.all_gather(logits, ctx.tp_axis,
-                                    axis=logits.ndim - 1, tiled=True)
+        with jax.named_scope("tp.exchange/logits"):
+            logits = jax.lax.all_gather(logits, ctx.tp_axis,
+                                        axis=logits.ndim - 1, tiled=True)
     return logits
 
 

@@ -98,6 +98,7 @@ from repro.models.common import spec_accept_counts
 from repro.models.transformer import ModelApi, make_model
 from repro.runtime.fault import InjectedFault
 from repro.serve.packed_params import (PackedInt4Leaf, anchor_block_size,
+                                       layout_tp_shards,
                                        make_packed_mixed_step,
                                        make_packed_params,
                                        make_packed_prefill_chunk,
@@ -105,7 +106,6 @@ from repro.serve.packed_params import (PackedInt4Leaf, anchor_block_size,
                                        make_packed_serve_step,
                                        make_packed_verify_step,
                                        packed_param_shardings,
-                                       repack_splitn_for_tp,
                                        weight_stream_bytes,
                                        weight_stream_bytes_local)
 from repro.serve.policy import FormatPolicy, SpecConfig
@@ -114,6 +114,36 @@ from repro.serve.slo import SLOClass, tier_rank
 DENSE_BF16 = "bf16"   # pseudo-format: dense anchor-precision weights
 
 MIN_PREFILL_BUCKET = 8
+
+
+# Collectives a tensor-parallel step can run, by jaxpr primitive, and
+# whether their payload is the operand (a reduction) or the result (a
+# gather: every chip ends up holding the whole).
+_COLLECTIVES = {"psum": "in", "all_gather": "out", "reduce_scatter": "in",
+                "all_to_all": "in", "ppermute": "in"}
+
+
+def _collective_bytes(jaxpr) -> int:
+    """Bytes of every collective a jaxpr runs, from its shapes: a
+    reduction counts its operands, a gather its result, each once per run
+    (a scan body times its length). Inside shard_map the shapes are one
+    chip's, which for a psum is the whole of what is summed."""
+    total = 0
+    for e in jaxpr.eqns:
+        side = _COLLECTIVES.get(e.primitive.name)
+        if side is not None:
+            vs = e.invars if side == "in" else e.outvars
+            total += sum(v.aval.size * v.aval.dtype.itemsize for v in vs)
+        subs = [j for v in e.params.values()
+                for j in (v if isinstance(v, (tuple, list)) else (v,))
+                if hasattr(j, "eqns") or hasattr(getattr(j, "jaxpr", None),
+                                                 "eqns")]
+        inner = [_collective_bytes(getattr(j, "jaxpr", j)) for j in subs]
+        if inner:
+            # a cond runs one branch; anything else runs each sub-jaxpr
+            n = max(inner) if e.primitive.name == "cond" else sum(inner)
+            total += n * int(e.params.get("length", 1))
+    return total
 
 
 def _bucket_len(plen: int, cap: int) -> int:
@@ -299,6 +329,19 @@ class ElasticEngine:
     are bit-identical to sequential admission (greedy and seeded) across
     all layout/contract pairings; the tests in tests/test_mixed_batch.py
     hold that line. Requires ``prefill_chunk``.
+
+    ``mesh`` (a mesh with a ``'model'`` axis) serves the model tensor
+    parallel over that axis (docs/serving_internals.md §11). Each chip's
+    d_ff share is laid out once at a tile-aligned width when a rung is
+    built (``layout_tp_shards``). ``donate_anchor`` says whether the engine
+    takes over an anchor whose leaves already live on its mesh: at the
+    anchor's rung each leaf the layout pads is deleted as soon as its
+    padded copy exists, and ``self.anchor`` holds the padded leaves, so
+    HBM never holds a second copy of the MLP. The caller's ``AnchorModel``
+    is then spent (read ``engine.anchor``), and a second engine built from
+    it refuses to build a rung. Pass False where engines share one anchor
+    (``ReplicaSet`` does): the layout then copies, which needs room for
+    both copies.
     """
 
     def __init__(self, api: ModelApi, anchor: AnchorModel, *,
@@ -318,9 +361,10 @@ class ElasticEngine:
                  fault_injector=None,
                  speculative: Optional[SpecConfig] = None,
                  admission_order: str = "fifo",
-                 mesh=None):
+                 mesh=None, donate_anchor: bool = True):
         self.api = api
         self.anchor = anchor
+        self.donate_anchor = donate_anchor
         self.slots = batch_slots
         self.max_len = max_len
         self.policy = policy or FormatPolicy(anchor.fmt_name)
@@ -388,6 +432,11 @@ class ElasticEngine:
                 if v % tp}
             # Row-parallel packed scales tile the contraction dim by the MX
             # block: those dims must split into whole scale rows per shard.
+            # A share that splits whole but is not tile-aligned (qwen2-72b's
+            # d_ff over 4 chips: 7392) is fine: weights_for lays each chip's
+            # d_ff share out once at a tile-aligned width (zero codes; see
+            # layout_tp_shards), and head widths are read per call as they
+            # are.
             bad.update({k: v for k, v in {
                 "n_heads*head_dim": cfg_g.n_heads * cfg_g.hd,
                 "d_ff": cfg_g.d_ff}.items() if v % bs_tp})
@@ -395,11 +444,16 @@ class ElasticEngine:
                 raise ValueError(
                     f"mesh 'model' axis size {tp} cannot shard this "
                     f"config: {bad} not divisible (block_size="
-                    f"{self._block_size})")
+                    f"{self._block_size}). Each of heads, kv heads and "
+                    "vocab must divide by tp, and n_heads*head_dim and "
+                    "d_ff by tp * block_size; a d_ff share that the "
+                    "kernel tiles do not divide is padded at pack time "
+                    "(layout_tp_shards), not refused")
             self._tp = tp
         self._weights: Dict[str, object] = {}       # fmt -> serving pytree
         self._fmt_swaps = 0
         self._ticks = 0
+        self._tp_exchange_bytes = 0   # collectives' bytes, all steps run
         self._tokens_out = 0
         self.current_fmt: Optional[str] = None
         # Length bucketing needs exact masking of right-padded prompts; the
@@ -578,7 +632,15 @@ class ElasticEngine:
                 cache_shape)
             self._cache_shardings = jax.tree_util.tree_map(
                 lambda p: NamedSharding(self.mesh, p), self._cache_pspecs)
+            # Each chip allocates only its own kv heads' share: a pool made
+            # whole on one chip and then split would have to fit there
+            # beside that chip's weights (qwen2-72b at tp 4: 5.5 GB of pool
+            # on top of 10.3 GB of weights).
+            self._new_cache = jax.jit(
+                functools.partial(self._init_cache, self.slots),
+                out_shardings=self._cache_shardings)
         else:
+            self._new_cache = functools.partial(self._init_cache, self.slots)
             self._cache_pspecs = None
             self._cache_shardings = None
         # Per-slot RNG: reseeded from (engine key, rid) at admission.
@@ -686,12 +748,16 @@ class ElasticEngine:
         outputs are bit-identical across shards BY CONSTRUCTION (the head
         all_gathers full logits everywhere), which the static replication
         checker cannot prove through psum-into-bias arithmetic.
+
+        Each call also counts its collectives' bytes (``_add_exchange``),
+        read once per call signature from the jaxpr the step traces.
         """
         if self.mesh is None:
             return jax.jit(fn)
         from jax.sharding import PartitionSpec
         from jax import shard_map
         compiled: Dict = {}
+        exchange: Dict = {}     # call signature -> its collectives' bytes
 
         def call(weights, *rest):
             w_specs = jax.tree_util.tree_map(
@@ -706,8 +772,24 @@ class ElasticEngine:
                 compiled[key] = jax.jit(shard_map(
                     fn, mesh=self.mesh, in_specs=tuple(in_specs),
                     out_specs=tuple(out_specs), check_vma=False))
-            return compiled[key](weights, *rest)
+            step = compiled[key]
+            sig = (key, tuple((np.shape(l), str(getattr(l, "dtype", "")))
+                              for l in jax.tree_util.tree_leaves(
+                                  (weights, rest))))
+            if sig not in exchange:
+                # the jaxpr jit traces anyway (trace() fills its cache)
+                exchange[sig] = _collective_bytes(
+                    step.trace(weights, *rest).jaxpr)
+            self._add_exchange(exchange[sig])
+            return step(weights, *rest)
         return call
+
+    def _add_exchange(self, nbytes: int) -> None:
+        """Count one meshed step's collective bytes, for the open tick's
+        record and ``stats()["tp_exchange_bytes"]``."""
+        self._tp_exchange_bytes += nbytes
+        if self._tick_rec is not None:
+            self._tick_rec["exchange_bytes"] += nbytes
 
     def _weight_shardings(self, w):
         """NamedShardings placing a serving weight tree on the TP mesh —
@@ -768,6 +850,7 @@ class ElasticEngine:
         packing at 4 bits); hits are free.
         """
         if fmt_name not in self._weights:
+            self._check_anchor()
             with self._phase("convert", fmt=fmt_name):
                 if self._serves_packed(fmt_name):
                     w = make_packed_params(self.anchor, self._template,
@@ -776,13 +859,7 @@ class ElasticEngine:
                 else:
                     w = self.dense_weights_for(fmt_name)
                 if self.mesh is not None:
-                    shardings = self._weight_shardings(w)
-                    # split-N int4 nibbles interleave the output halves; a
-                    # column-sharded leaf must be repacked per shard first
-                    # (see repack_splitn_for_tp) or half the head /
-                    # ff-block contributions pair wrong inside shard_map.
-                    w = repack_splitn_for_tp(w, shardings, self._tp)
-                    w = jax.device_put(w, shardings)
+                    w = self._lay_out_for_mesh(w, fmt_name)
             self._weights[fmt_name] = w
             self._fmt_swaps += 1
             if self.policy.cost is not None:
@@ -797,6 +874,53 @@ class ElasticEngine:
                     fmt_name, wb,
                     self._attn_read_span * self._attn_token_bytes_chip)
         return self._weights[fmt_name]
+
+    def _check_anchor(self) -> None:
+        """Refuse to build a rung from an anchor another engine has taken
+        over (``donate_anchor``), naming the cause, rather than fail later
+        on a deleted array."""
+        dead = [k for k, v in {**self.anchor.quantized,
+                               **self.anchor.raw}.items()
+                if any(getattr(x, "is_deleted", lambda: False)()
+                       for x in jax.tree_util.tree_leaves(v))]
+        if dead:
+            raise ValueError(
+                f"anchor leaves {dead[:3]} have been deleted: another "
+                "engine on this mesh took the anchor over "
+                "(donate_anchor=True). Build every engine that shares one "
+                "anchor with donate_anchor=False")
+
+    def _lay_out_for_mesh(self, w, fmt_name: str):
+        """``w`` placed on the TP mesh, each chip's shard laid out once.
+
+        Each chip's d_ff share is zero-padded to the width the fused GEMM
+        tiles without a pad (``layout_tp_shards``), so no step copies a
+        weight to pad it; split-N int4 nibbles, which interleave the output
+        halves, are re-nibbled per shard, or half the head / ff-block
+        contributions would pair wrong inside shard_map. At the anchor's
+        own rung the served leaves ARE the anchor's: the padded copies
+        replace them in this engine's anchor (later rungs convert from it
+        and come out laid out already), and with ``donate_anchor`` inputs
+        that already live on the mesh are deleted once copied, since a
+        second copy of a sharded MLP would not fit beside the first.
+        """
+        shardings = self._weight_shardings(w)
+        own = self.donate_anchor and fmt_name == self.anchor.fmt_name \
+            and self._serves_packed(fmt_name)
+        w, laid = layout_tp_shards(w, shardings, self.api.param_axes(),
+                                   self._tp, donate=own)
+        w = jax.device_put(w, self._weight_shardings(w))
+        if own and laid:
+            flat = {jax.tree_util.keystr(p): l for p, l in
+                    jax.tree_util.tree_flatten_with_path(
+                        w, is_leaf=lambda x: isinstance(x, MXTensor))[0]}
+            a = self.anchor
+            self.anchor = AnchorModel(
+                quantized={k: flat[k] for k in a.quantized},
+                raw={k: flat.get(k, v) if k in laid else v
+                     for k, v in a.raw.items()},
+                fmt_name=a.fmt_name)
+        return w
 
     def dense_weights_for(self, fmt_name: str):
         """Dense reference weights at ``fmt_name`` — numerically identical to
@@ -831,7 +955,7 @@ class ElasticEngine:
             raise ValueError(f"prompt length {prompt.size} outside "
                              f"[1, {self.prompt_capacity}]")
         b, c = self.slots, self.prefill_chunk
-        cache = self._init_cache(b)
+        cache = self._new_cache()
         bt = np.zeros(cache["block_table"].shape, np.int32)
         n_pages = -(-prompt.size // self.kv_page_size)
         bt[0, :n_pages] = np.arange(1, n_pages + 1)
@@ -1133,10 +1257,9 @@ class ElasticEngine:
             pending = list(requests)
             active: List[Optional[Request]] = [None] * b
             slot_len = [0] * b             # host mirror of cache_len
-            cache = self._init_cache(b)
-            if self.mesh is not None:
-                # Pools/dense KV shard over kv heads; block table replicated.
-                cache = jax.device_put(cache, self._cache_shardings)
+            # on a mesh: pools/dense KV sharded over kv heads, block table
+            # replicated
+            cache = self._new_cache()
             cache_len = jnp.zeros((b,), jnp.int32)
             tokens = jnp.zeros((b, 1), jnp.int32)
             pinned: Optional[str] = None   # format for this batch's lifetime
@@ -1558,6 +1681,14 @@ class ElasticEngine:
                                              fail)
                                 filling = None
                             else:
+                                if not final:
+                                    # Nothing of a non-final chunk is
+                                    # fetched: wait for it, or the next
+                                    # dispatch runs ahead and every chained
+                                    # step's output pool is held at once
+                                    # (qwen2-72b at tp 4: 1.39 GB a chip
+                                    # each, in 5.7 GB of headroom).
+                                    jax.block_until_ready(cache)
                                 cache_len = cache_len.at[i].set(new_len)
                                 fill_cursor = start + take
                                 if final:
@@ -2187,7 +2318,10 @@ class ElasticEngine:
         dispatch's start to the end of the first fetch after it — the step
         latency the host observed. Each is None when the tick dispatched
         (or fetched) nothing; a chunk that runs alone with no logits to
-        read is never fetched.
+        read is never fetched. ``exchange_bytes`` sums the collectives of
+        the tick's steps on a tensor-parallel mesh (``_mesh_jit``): each
+        all-reduce's operand and the logits' all-gather result, from the
+        shapes the step ran; 0 on one chip.
         """
         rec = self._tick_rec
         self.tick_trace.append({"prefill_tokens": prefill_tokens,
@@ -2201,7 +2335,8 @@ class ElasticEngine:
                                 "phase_s": dict(rec["phase_s"]),
                                 "dispatched_s": rec["dispatched_s"],
                                 "fetched_s": rec["fetched_s"],
-                                "step_s": rec["step_s"]})
+                                "step_s": rec["step_s"],
+                                "exchange_bytes": rec["exchange_bytes"]})
 
     @contextlib.contextmanager
     def _phase(self, name: str, **attrs):
@@ -2221,7 +2356,7 @@ class ElasticEngine:
         if name == "tick":
             self._tick_rec = {"kind": "idle", "phase_s": {},
                               "dispatched_s": None, "fetched_s": None,
-                              "step_s": None}
+                              "step_s": None, "exchange_bytes": 0}
             self._step_t = None
         rec, nested = self._tick_rec, self._in_phase
         self._in_phase = name != "tick"
@@ -2464,10 +2599,10 @@ class ElasticEngine:
         tmpl_leaves, treedef = jax.tree_util.tree_flatten(
             jax.eval_shape(lambda: self._init_cache(self.slots)))
         cache = jax.tree_util.tree_unflatten(treedef, [
-            jnp.asarray(arrays[f"cache_{n:04d}"]).astype(t.dtype)
+            np.asarray(arrays[f"cache_{n:04d}"]).astype(t.dtype)
             for n, t in enumerate(tmpl_leaves)])
-        if self.mesh is not None:
-            cache = jax.device_put(cache, self._cache_shardings)
+        # from the host straight to each chip's share (see _new_cache)
+        cache = jax.device_put(cache, self._cache_shardings)
         self._key = jnp.asarray(arrays["engine_key"])
         self._slot_keys = jnp.asarray(arrays["slot_keys"])
         if "slot_temp" in arrays:
@@ -2565,6 +2700,7 @@ class ElasticEngine:
             "weight_bytes_per_chip": {f: weight_stream_bytes_local(t)
                                       for f, t in self._weights.items()},
             "mesh": self._mesh_str(),
+            "tp_exchange_bytes": self._tp_exchange_bytes,
             "fmt_swaps": self._fmt_swaps,
             "ticks": self._ticks,
             "tokens_out": self._tokens_out,

@@ -237,9 +237,209 @@ def repack_splitn_for_tp(packed_params, shardings, tp: int):
              for s in range(n_shards)], axis=-1)
         return dataclasses.replace(leaf, packed=packed)
 
-    is_c = lambda x: isinstance(x, (MXTensor, PackedInt4Leaf))
     return jax.tree_util.tree_map(fix, packed_params, shardings,
-                                  is_leaf=is_c)
+                                  is_leaf=_is_container)
+
+
+def _is_container(x) -> bool:
+    return isinstance(x, (MXTensor, PackedInt4Leaf))
+
+
+def _flat_axes(axes_tree) -> Dict[str, tuple]:
+    """keystr path -> logical axes of each leaf of a ``param_axes`` tree."""
+    is_ax = lambda x: isinstance(x, tuple) and all(
+        isinstance(e, (str, type(None))) for e in x)
+    return {jax.tree_util.keystr(p): a for p, a in
+            jax.tree_util.tree_flatten_with_path(axes_tree,
+                                                 is_leaf=is_ax)[0]}
+
+
+def _ff_axis(axes) -> Optional[int]:
+    """Index of the d_ff ("mlp") logical axis, or None."""
+    return axes.index("mlp") if axes and "mlp" in axes else None
+
+
+def _gemm_view(leaf):
+    """(array whose last two dims are (K, N or N/2), fmt, kind) of a
+    container the fused GEMM reads, else None."""
+    if isinstance(leaf, MXTensor):
+        return leaf.codes, leaf.fmt, "mx"
+    if isinstance(leaf, PackedInt4Leaf) and leaf.layout == "splitn":
+        return leaf.packed, get_format(leaf.fmt_name,
+                                       leaf_block_size(leaf)), "int4"
+    return None
+
+
+def tp_ff_width(packed_params, axes_tree, tp: int):
+    """(each chip's d_ff share now, the width it is laid out at), or None
+    where no GEMM leaf has a d_ff axis.
+
+    The share is N of the column-parallel leaves (w_gate, w_up) and K of
+    the row-parallel one (w_down), so one width serves both: the smallest
+    at which ``dispatch.weight_layout`` pads none of them, at their own
+    format and kind. The padded columns then run through the activation
+    (silu(0) * 0 = 0, gelu(0) = 0) into w_down's zero rows, and nothing is
+    sliced. A share the kernels already tile whole, or at lane and K-unit
+    multiples, is its own width. At qwen2-72b's d_ff 29568 over 4 chips:
+    7392 -> 7424 (w_gate, w_up at tn 256; w_down at tk 256).
+    """
+    from repro.kernels.dispatch import weight_layout
+    flat_a = _flat_axes(axes_tree)
+    share, roles = None, []
+    for pth, leaf in jax.tree_util.tree_flatten_with_path(
+            packed_params, is_leaf=_is_container)[0]:
+        ax = _ff_axis(flat_a.get(jax.tree_util.keystr(pth)))
+        view = _gemm_view(leaf) if ax is not None else None
+        if view is None:
+            continue
+        arr, fmt, kind = view
+        k, n = arr.shape[-2], arr.shape[-1] * (2 if kind == "int4" else 1)
+        if ax == arr.ndim - 1:
+            share = n // tp
+            roles.append(lambda w, k=k, f=fmt, c=kind:
+                         weight_layout(k, w, f, c)[1])
+        elif ax == arr.ndim - 2:
+            share = k // tp
+            roles.append(lambda w, n=n, f=fmt, c=kind:
+                         weight_layout(w, n, f, c)[0])
+    if not roles:
+        return None
+    width = share
+    while True:
+        nxt = max(r(width) for r in roles)
+        if nxt == width:
+            return share, width
+        width = nxt
+
+
+def _pad_shards(a: jax.Array, axis: int, tp: int, width: int) -> jax.Array:
+    """``a`` with each of the ``tp`` contiguous blocks of ``axis`` (the
+    chips' shards) zero-padded at its end to ``width``."""
+    shp = a.shape
+    blk = shp[axis] // tp
+    a = a.reshape(shp[:axis] + (tp, blk) + shp[axis + 1:])
+    pad = [(0, 0)] * a.ndim
+    pad[axis + 1] = (0, width - blk)
+    return jnp.pad(a, pad).reshape(shp[:axis] + (tp * width,)
+                                   + shp[axis + 1:])
+
+
+def _pad_blocks(a: jax.Array, axis: int, tp: int, width: int) -> jax.Array:
+    """``_pad_shards`` of a stacked leaf, one layer at a time: padding the
+    minor axis of a whole stacked leaf at once takes a temporary as large
+    as the leaf (2.4 GB a chip at qwen2-72b's 40 layers), one layer 61 MB."""
+    return jax.lax.map(
+        functools.partial(_pad_shards, axis=axis - 1, tp=tp, width=width), a)
+
+
+def _pad_splitn_blocks(packed: jax.Array, tp: int, width: int) -> jax.Array:
+    """A stacked split-N nibble array whose unpacked columns are padded per
+    shard, packed split-N again, one layer at a time."""
+    def one(p):
+        codes = unpack_int4_splitn_jnp(p)
+        return pack_int4_splitn_jnp(_pad_shards(codes, codes.ndim - 1, tp,
+                                                width))
+    return jax.lax.map(one, packed)
+
+
+def layout_tp_shards(packed_params, shardings, axes_tree, tp: int, *,
+                     donate: bool = False):
+    """A tensor-parallel serving tree laid out for its chips, once.
+
+    Each chip's share of d_ff is zero-padded to ``tp_ff_width``'s width:
+    codes (zero codes dequantize to exactly 0 in every MX format), scales
+    (exponent 0, so finite) and d_ff-sharded raw leaves (a ``b_up`` bias,
+    zero). Each padded array is sharded by ``shardings`` (the unpadded
+    tree's; a padded dim still divides by tp), so every shard of a GEMM
+    leaf reaches the kernel tile-aligned and no step pads a weight again.
+    Split-N int4 leaves are then re-nibbled per shard
+    (``repack_splitn_for_tp``). A padded int4 leaf's ``shape`` changes, so
+    the caller places the result by the padded tree's own shardings. A
+    tree whose shares are already aligned comes back unchanged.
+
+    ``donate``: delete each padded leaf's input array, where it already
+    lives on the mesh, as soon as its padded copy exists, so HBM holds one
+    extra stacked leaf at a time and not a second copy of the MLP (at
+    qwen2-72b's 40-layer cut, 7.5 GB a chip). Every other holder of those
+    arrays is left with deleted ones: only a caller that owns them may ask
+    for this (``ElasticEngine``'s ``donate_anchor``), and it re-points what
+    referenced them (its anchor).
+
+    Each padded GEMM share is pinned (``dispatch.pin_layout``) to the
+    tiles it was padded for, so that every step reads it with them.
+
+    Returns (tree, paths of the leaves that were padded).
+    """
+    from repro.kernels.dispatch import pin_layout
+    fw = tp_ff_width(packed_params, axes_tree, tp)
+    if fw is None or fw[0] == fw[1] or tp <= 1:
+        return repack_splitn_for_tp(packed_params, shardings, tp), []
+    share, width = fw
+    flat_a = _flat_axes(axes_tree)
+
+    def put(fn, a, sharding, **kw):
+        out = jax.jit(functools.partial(fn, tp=tp, **kw),
+                      out_shardings=sharding)(a)
+        if donate and getattr(a.sharding, "mesh", None) == sharding.mesh:
+            a.delete()
+        return out
+
+    def blocks(a, axis, sharding, w):
+        return put(_pad_blocks, a, sharding, axis=axis, width=w)
+
+    laid = []
+
+    def lay(pth, leaf, shd):
+        path = jax.tree_util.keystr(pth)
+        ax = _ff_axis(flat_a.get(path))
+        if ax is None:
+            return leaf
+        if not _is_container(leaf):
+            if leaf.shape[ax] != share * tp:
+                return leaf
+            laid.append(path)
+            return blocks(leaf, ax, shd, width)
+        if _gemm_view(leaf) is None:
+            raise ValueError(f"{path}: a {leaf.layout} int4 leaf has no "
+                             "tile layout to pad its d_ff share to")
+        arr, fmt, kind = _gemm_view(leaf)
+        nd = arr.ndim
+        # each chip reads its padded share with the tiles it was padded for
+        if ax == nd - 1:
+            k = arr.shape[-2]
+            pin_layout(k, share, k, width, fmt, kind)
+        else:
+            n = arr.shape[-1] * (2 if kind == "int4" else 1)
+            pin_layout(share, n, width, n, fmt, kind)
+        # scales are moved-last: the contraction axis (ndim-2) goes last
+        s_ax = nd - 1 if ax == nd - 2 else ax - (ax > nd - 2)
+        bs = leaf_block_size(leaf) if isinstance(leaf, PackedInt4Leaf) \
+            else leaf.fmt.block_size
+        s_w = width // bs if ax == nd - 2 else width
+        scales = blocks(leaf.scale_exp, s_ax, shd.scale_exp, s_w)
+        laid.append(path)
+        if isinstance(leaf, MXTensor):
+            return MXTensor(codes=blocks(leaf.codes, ax, shd.codes, width),
+                            scale_exp=scales, fmt=leaf.fmt,
+                            block_axis=leaf.block_axis)
+        if ax == nd - 1:
+            packed = put(_pad_splitn_blocks, leaf.packed, shd.packed,
+                         width=width)
+        else:
+            packed = blocks(leaf.packed, ax, shd.packed, width)
+        shape = list(leaf.shape)
+        shape[ax] = tp * width
+        return dataclasses.replace(leaf, packed=packed, scale_exp=scales,
+                                   shape=tuple(shape))
+
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        packed_params, is_leaf=_is_container)
+    shd_leaves = treedef.flatten_up_to(shardings)
+    out = jax.tree_util.tree_unflatten(
+        treedef, [lay(p, l, s) for (p, l), s in zip(leaves, shd_leaves)])
+    mesh = next(s for s in jax.tree_util.tree_leaves(shardings)).mesh
+    return repack_splitn_for_tp(
+        out, packed_param_shardings(out, axes_tree, mesh), tp), laid
 
 
 def packed_param_shardings(packed_abstract, axes_tree, mesh, rules=None):
@@ -257,12 +457,7 @@ def packed_param_shardings(packed_abstract, axes_tree, mesh, rules=None):
     from jax.sharding import NamedSharding
     from repro.sharding.rules import spec_for_axes
 
-    is_ax = lambda x: isinstance(x, tuple) and all(
-        isinstance(e, (str, type(None))) for e in x)
-
-    flat_a = {jax.tree_util.keystr(p): a for p, a in
-              jax.tree_util.tree_flatten_with_path(
-                  axes_tree, is_leaf=is_ax)[0]}
+    flat_a = _flat_axes(axes_tree)
 
     def container(path_str, leaf):
         axes = flat_a[path_str]
@@ -294,8 +489,7 @@ def packed_param_shardings(packed_abstract, axes_tree, mesh, rules=None):
                                                  rules))
 
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        packed_abstract,
-        is_leaf=lambda x: isinstance(x, (MXTensor, PackedInt4Leaf)))
+        packed_abstract, is_leaf=_is_container)
     return jax.tree_util.tree_unflatten(
         treedef, [container(jax.tree_util.keystr(p), l)
                   for p, l in leaves])

@@ -66,6 +66,8 @@ class ReplicaSet:
             meshes = [None] * n_replicas
         self.n_replicas = n_replicas
         self.tp = tp
+        # the replicas share one anchor: none may take it over
+        engine_kwargs.setdefault("donate_anchor", n_replicas == 1)
         self.engines: List[ElasticEngine] = [
             ElasticEngine(api, anchor, mesh=m, **engine_kwargs)
             for m in meshes]

@@ -25,13 +25,18 @@ from repro.kernels import dispatch
 from repro.kernels import paged_attention as pa
 from repro.serve.packed_params import pack_leaf_int4
 
-# (K, N) projections: smollm-135m (d_model 576, kv width 3*64, d_ff 1536)
-# and qwen3-4b (d_model 2560, q width 32*128, d_ff 9728).
+# (K, N) projections: smollm-135m (d_model 576, kv width 3*64, d_ff 1536),
+# qwen3-4b (d_model 2560, q width 32*128, d_ff 9728), and one chip's shard
+# of qwen2-72b on a (1, 4) mesh (d_model 8192, 16 q and 2 kv heads of 128,
+# its d_ff share 29568 / 4 = 7392 as laid out at pack time, 7424).
 GEMMS = {
     "smollm-qo": (576, 576), "smollm-kv": (576, 192),
     "smollm-up": (576, 1536), "smollm-down": (1536, 576),
     "qwen3-q": (2560, 4096), "qwen3-up": (2560, 9728),
     "qwen3-down": (9728, 2560),
+    "qwen2tp4-q": (8192, 2048), "qwen2tp4-kv": (8192, 256),
+    "qwen2tp4-o": (2048, 8192), "qwen2tp4-up": (8192, 7424),
+    "qwen2tp4-down": (7424, 8192),
 }
 DECODE_M = 8
 
@@ -92,9 +97,30 @@ def test_dequant_gemm_compiles_for_v5e(one_chip, mosaic, gemm, fmt_name):
                  _sds(one_chip, leaf.scale_exp.shape, leaf.scale_exp.dtype))
 
 
-# (H, Hkv, D): smollm-135m, qwen3-4b, and qwen3-4b's per-chip share on a
-# (1, 4) tensor-parallel mesh.
-HEADS = {"smollm": (9, 3, 64), "qwen3": (32, 8, 128), "qwen3-tp4": (8, 2, 128)}
+@pytest.mark.parametrize("m", [DECODE_M, 1024])
+@pytest.mark.parametrize("fmt_name", ["mxint8", "mxint6"])
+def test_pinned_layout_gemm_compiles_for_v5e(one_chip, mosaic, monkeypatch,
+                                             m, fmt_name):
+    """qwen2-72b's w_gate share at tp 4 as laid out (8192 x 7424), read at
+    the tn it was padded for (256, ``dispatch.pin_layout``), at a decode
+    and at a mixed tick's M."""
+    monkeypatch.setattr(dispatch, "_LAID_OUT", {})
+    fmt = get_format(fmt_name, 32)
+    assert dispatch.pin_layout(8192, 7392, 8192, 7424, fmt) == (256, 512)
+    w = jax.ShapeDtypeStruct((8192, 7424), jnp.float32)
+    leaf = jax.eval_shape(lambda a: quantize(a, fmt, axis=0), w)
+    hlo = _compile(lambda x, c, s: dispatch.qmatmul_mx(x, c, s.T, fmt),
+                   _sds(one_chip, (m, 8192), jnp.bfloat16),
+                   _sds(one_chip, leaf.codes.shape, leaf.codes.dtype),
+                   _sds(one_chip, leaf.scale_exp.shape,
+                        leaf.scale_exp.dtype)).as_text()
+    assert "s8[8192,7424]" in hlo and "pad(" not in hlo
+
+
+# (H, Hkv, D): smollm-135m, qwen3-4b, and the per-chip shares of qwen3-4b
+# and of qwen2-72b (a GQA group of 8) on a (1, 4) tensor-parallel mesh.
+HEADS = {"smollm": (9, 3, 64), "qwen3": (32, 8, 128), "qwen3-tp4": (8, 2, 128),
+         "qwen2-tp4": (16, 2, 128)}
 B, PAGES, PS, MAX_PAGES, CHUNK = 8, 64, 16, 33, 16
 
 
@@ -123,12 +149,12 @@ def test_paged_multi_query_kernel_compiles_for_v5e(one_chip, heads, window):
 
 
 @pytest.mark.parametrize("window", [None, 64])
-@pytest.mark.parametrize("heads", ["smollm", "qwen3"])
+@pytest.mark.parametrize("heads", ["smollm", "qwen3", "qwen2-tp4"])
 def test_paged_multi_query_narrow_fold_compiles_for_v5e(one_chip, heads,
                                                          window):
     """At the serving chunk of 64 lanes both branches of the narrow fold
     lower: NARROW_LANES * G query rows (24 at smollm-135m's G 3, 32 at
-    qwen3-4b's G 4) and the whole block of 64 * G."""
+    qwen3-4b's G 4, 64 at qwen2-72b's G 8) and the whole block of 64 * G."""
     h, hkv, d = HEADS[heads]
     c = 64
     assert c > pa.NARROW_LANES
